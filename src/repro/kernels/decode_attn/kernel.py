@@ -7,7 +7,8 @@ query heads of a KV group are processed together against each streamed
 Online softmax state (m, l, acc) lives in VMEM scratch across the KV sweep.
 
 Grid: (B * Hkv, S / TK).  Dynamic cache lengths are handled with a per-row
-``pos`` operand masking cols >= pos.
+``pos`` operand masking cols >= pos; ``pos`` is a scalar-prefetch operand
+(SMEM), read as a scalar per grid row.
 """
 from __future__ import annotations
 
@@ -16,12 +17,13 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
 DEFAULT_TK = 1024
 NEG_INF = -1e30
 
 
-def _decode_kernel(q_ref, k_ref, v_ref, pos_ref, o_ref, m_ref, l_ref, acc_ref,
+def _decode_kernel(pos_ref, q_ref, k_ref, v_ref, o_ref, m_ref, l_ref, acc_ref,
                    *, scale: float, tk: int):
     kb = pl.program_id(1)
 
@@ -31,7 +33,7 @@ def _decode_kernel(q_ref, k_ref, v_ref, pos_ref, o_ref, m_ref, l_ref, acc_ref,
         l_ref[...] = jnp.zeros_like(l_ref)
         acc_ref[...] = jnp.zeros_like(acc_ref)
 
-    pos = pos_ref[0, 0]
+    pos = pos_ref[pl.program_id(0)]
     # skip tiles entirely past the valid length
     @pl.when(kb * tk < pos)
     def _compute():
@@ -74,26 +76,26 @@ def decode_attention_pallas(q, k, v, pos, scale: float | None = None,
     qr = q.reshape(b, hkv, group, dh).reshape(b * hkv, group, dh)
     kr = k.reshape(b * hkv, s, dh)
     vr = v.reshape(b * hkv, s, dh)
-    pos_r = jnp.broadcast_to(pos[:, None], (b, hkv)).reshape(b * hkv, 1).astype(jnp.int32)
-    grid = (b * hkv, s // tk)
+    pos_r = jnp.broadcast_to(pos[:, None], (b, hkv)).reshape(b * hkv).astype(jnp.int32)
     out = pl.pallas_call(
         functools.partial(_decode_kernel, scale=scale, tk=tk),
-        grid=grid,
-        in_specs=[
-            pl.BlockSpec((1, group, dh), lambda bh, kb: (bh, 0, 0)),
-            pl.BlockSpec((1, tk, dh), lambda bh, kb: (bh, kb, 0)),
-            pl.BlockSpec((1, tk, dh), lambda bh, kb: (bh, kb, 0)),
-            pl.BlockSpec((1, 1), lambda bh, kb: (bh, 0)),
-        ],
-        out_specs=pl.BlockSpec((1, group, dh), lambda bh, kb: (bh, 0, 0)),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=1,
+            grid=(b * hkv, s // tk),
+            in_specs=[
+                pl.BlockSpec((1, group, dh), lambda bh, kb, p: (bh, 0, 0)),
+                pl.BlockSpec((1, tk, dh), lambda bh, kb, p: (bh, kb, 0)),
+                pl.BlockSpec((1, tk, dh), lambda bh, kb, p: (bh, kb, 0)),
+            ],
+            out_specs=pl.BlockSpec((1, group, dh), lambda bh, kb, p: (bh, 0, 0)),
+            scratch_shapes=[_vmem((group, 1)), _vmem((group, 1)),
+                            _vmem((group, dh))],
+        ),
         out_shape=jax.ShapeDtypeStruct((b * hkv, group, dh), q.dtype),
-        scratch_shapes=[_vmem((group, 1)), _vmem((group, 1)), _vmem((group, dh))],
         interpret=interpret,
-    )(qr, kr, vr, pos_r)
+    )(pos_r, qr, kr, vr)
     return out.reshape(b, hkv, group, dh).reshape(b, h, dh)
 
 
 def _vmem(shape):
-    from jax.experimental.pallas import tpu as pltpu
-
     return pltpu.VMEM(shape, jnp.float32)

@@ -2,17 +2,21 @@
 
 GPU engines do group-by aggregation with hash tables + atomic scatter-adds.
 TPU has no fast scatter, so we restructure for the memory hierarchy and the
-systolic MXU: stream (TN, M) value tiles HBM->VMEM, build a (TN, TG) one-hot
-of group ids *in VMEM*, and accumulate partial aggregates with
-``one_hot.T @ values`` on the MXU.  The output tile (TG, M) stays resident in
-VMEM across the whole N sweep (grid minor axis) and is written back once per
-group tile.
+systolic MXU.  Fact rows sit on the 128-wide lane axis: (M, TN) value tiles,
+(1, TN) group-id and mask tiles and (P, TN) predicate tiles stream
+HBM->VMEM, a (TG, TN) one-hot of group ids is built *in VMEM* from a 2-D
+iota, and SUM accumulates ``values · onehotᵀ`` on the MXU into an (M, TG)
+output block.  The output block stays resident in VMEM across the whole N
+sweep (grid minor axis) and is written back once per group tile.
 
-Arithmetic intensity: the matmul spends 2·G flops per loaded value vs a 4-byte
-HBM read, so the kernel stays memory-bound (the roofline optimum for a
-reduction) for G up to ~800 groups per tile at v5e ratios — exactly the
-dashboard regime (grouping cardinalities of tens to hundreds).  MIN/MAX use a
-masked select-and-reduce on the VPU instead of the matmul.
+Rows-on-lanes matters for HBM: a measure block is (N, M) with M of 1-6, and
+an (N, M) operand in the TPU's (8, 128) tiling pads M to 128 lanes, so every
+copy of it costs N·128·4 bytes.  As (M, N) the same block costs N·M·4.
+
+MIN/MAX select through the one-hot on the VPU and reduce over the lane axis
+into rows of the output block.  N is never padded in HBM: the grid runs
+``cdiv(N, TN)`` row tiles and a global row-index guard drops the rows past N
+in the last, partial tile.
 """
 from __future__ import annotations
 
@@ -21,44 +25,125 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
-DEFAULT_TN = 1024  # fact rows per tile
-DEFAULT_TG = 512  # groups per tile; one-hot tile = TN*TG*4B = 2 MiB VMEM
+from .ref import IDENTITY
+
+DEFAULT_TN = 1024  # fact rows per tile (lanes)
+DEFAULT_TG = 512  # groups per tile; one-hot tile = TG*TN*4B = 2 MiB VMEM
+LANES = 128
 
 
-def _seg_agg_kernel(values_ref, ids_ref, mask_ref, out_ref, *, op: str, tg: int):
+def _round_up(x: int, m: int) -> int:
+    return -(-x // m) * m
+
+
+def _tiles(n: int, num_groups: int, tn: int, tg: int) -> tuple[int, int, int]:
+    """Row tile, group tile and padded group count: both tiles are lane
+    multiples; a row tile never exceeds N rounded up to a lane multiple."""
+    tn = min(_round_up(tn, LANES), _round_up(n, LANES))
+    tg = min(_round_up(tg, LANES), _round_up(num_groups, LANES))
+    return tn, tg, _round_up(num_groups, tg)
+
+
+def _nt_dot(a, b):
+    """(R, TN) x (TG, TN) -> (R, TG), contracting the lane axis, in full
+    f32 precision on the MXU (sums must match a float64 oracle)."""
+    return jax.lax.dot_general(
+        a, b, (((1,), (1,)), ((), ())), precision=jax.lax.Precision.HIGHEST,
+        preferred_element_type=jnp.float32)
+
+
+def _lane_reduce_row(x, op: str):
+    """(TG, TN) -> (1, TG): fold 128-lane slabs elementwise, transpose the
+    (TG, 128) partial and finish over sublanes, so the result lies along
+    lanes like a row of the output block."""
+    comb = jnp.minimum if op == "min" else jnp.maximum
+    part = x[:, :LANES]
+    for c in range(LANES, x.shape[1], LANES):
+        part = comb(part, x[:, c:c + LANES])
+    part = part.T
+    return (jnp.min if op == "min" else jnp.max)(part, axis=0, keepdims=True)
+
+
+def _accumulate(out_ref, values, ids, mask, *, op: str, tg: int):
+    """Fold one row tile into the resident (M, TG) output block.
+
+    values (M, TN) f32, ids (1, TN) int32, mask (1, TN) bool.  Masked-out
+    rows contribute the op identity.  SUM is NaN-safe: a NaN would poison
+    every group of the tile through 0 * NaN in the matmul, so cleaned values
+    and NaN indicators are reduced side by side, and only groups whose
+    qualifying rows carry a NaN become NaN.  MIN/MAX select through the
+    one-hot, so NaNs stay in their own group."""
     nb = pl.program_id(1)
 
     @pl.when(nb == 0)
     def _init():
-        if op == "sum":
-            out_ref[...] = jnp.zeros_like(out_ref)
-        elif op == "min":
-            out_ref[...] = jnp.full_like(out_ref, jnp.inf)
-        else:
-            out_ref[...] = jnp.full_like(out_ref, -jnp.inf)
+        out_ref[...] = jnp.full(out_ref.shape, IDENTITY[op], jnp.float32)
 
-    gb = pl.program_id(0)
-    values = values_ref[...]  # (TN, M) f32
-    ids = ids_ref[...][:, 0]  # (TN,)
-    mask = mask_ref[...][:, 0] > 0.5  # (TN,)
-    tn = values.shape[0]
-    local = ids - gb * tg
-    onehot = (local[:, None] == jax.lax.broadcasted_iota(jnp.int32, (tn, tg), 1)) & mask[:, None]
+    tn = values.shape[1]
+    local = ids - pl.program_id(0) * tg
+    onehot = (jax.lax.broadcasted_iota(jnp.int32, (tg, tn), 0) == local) & mask
     if op == "sum":
+        nan = jnp.isnan(values)
         oh = onehot.astype(jnp.float32)
-        out_ref[...] += jax.lax.dot_general(
-            oh, values, (((0,), (0,)), ((), ())), preferred_element_type=jnp.float32
-        )  # (TG, M)
-    else:
-        ident = jnp.inf if op == "min" else -jnp.inf
-        m = values.shape[1]
-        # VPU path: per-measure masked reduce over the row axis
-        for j in range(m):
-            vj = jnp.where(onehot, values[:, j][:, None], ident)  # (TN, TG)
-            red = jnp.min(vj, axis=0) if op == "min" else jnp.max(vj, axis=0)
-            cur = out_ref[:, j]
-            out_ref[:, j] = jnp.minimum(cur, red) if op == "min" else jnp.maximum(cur, red)
+        acc = _nt_dot(jnp.where(mask & ~nan, values, 0.0), oh)
+        hits = _nt_dot((mask & nan).astype(jnp.float32), oh)
+        out_ref[...] += acc + jnp.where(hits > 0, jnp.nan, 0.0)
+        return
+    comb = jnp.minimum if op == "min" else jnp.maximum
+    for j in range(values.shape[0]):
+        vj = jnp.where(onehot, values[j:j + 1, :], IDENTITY[op])  # (TG, TN)
+        out_ref[j:j + 1, :] = comb(out_ref[j:j + 1, :], _lane_reduce_row(vj, op))
+
+
+def _row_guard(tn: int, n: int):
+    """(1, TN) validity of this tile's rows: False past row N."""
+    rows = pl.program_id(1) * tn + jax.lax.broadcasted_iota(jnp.int32, (1, tn), 1)
+    return rows < n
+
+
+def _seg_agg_kernel(values_ref, ids_ref, *rest, op: str, tg: int, n: int,
+                    has_mask: bool):
+    out_ref = rest[-1]
+    tn = values_ref.shape[1]
+    mask = _row_guard(tn, n)
+    if has_mask:
+        mask = mask & (rest[0][...] > 0.5)
+    _accumulate(out_ref, values_ref[...].astype(jnp.float32), ids_ref[...],
+                mask, op=op, tg=tg)
+
+
+def _row_pad(x, n: int, tn: int):
+    """Pad the lane (row) axis up to one row tile, only when N < TN."""
+    return jnp.pad(x, ((0, 0), (0, tn - n))) if n < tn else x
+
+
+def seg_agg_lanes(vt, ids, mask, num_groups: int, op: str = "sum",
+                  tn: int = DEFAULT_TN, tg: int = DEFAULT_TG,
+                  interpret: bool = False):
+    """Lane-major core: vt (M, N), ids (N,) int32, mask (N,) or None ->
+    (M, num_groups) f32.  Rows with mask <= 0.5 contribute the op identity
+    (``mask=None`` keeps every row); NaN handling as in ``_accumulate``.
+    Callers that build their value block inside a jitted program (the
+    shared-scan batch) call this directly, in the layout the kernel reads."""
+    m, n = vt.shape
+    tn, tg, gp = _tiles(n, num_groups, tn, tg)
+    operands = [_row_pad(jnp.asarray(vt, jnp.float32), n, tn),
+                _row_pad(jnp.asarray(ids, jnp.int32)[None, :], n, tn)]
+    if mask is not None:
+        operands.append(_row_pad(jnp.asarray(mask, jnp.float32)[None, :], n, tn))
+    row_spec = functools.partial(pl.BlockSpec, index_map=lambda gb, nb: (0, nb))
+    out = pl.pallas_call(
+        functools.partial(_seg_agg_kernel, op=op, tg=tg, n=n,
+                          has_mask=mask is not None),
+        grid=(gp // tg, pl.cdiv(operands[0].shape[1], tn)),
+        in_specs=[row_spec((m, tn))] + [row_spec((1, tn))] * (len(operands) - 1),
+        out_specs=pl.BlockSpec((m, tg), lambda gb, nb: (0, gb)),
+        out_shape=jax.ShapeDtypeStruct((m, gp), jnp.float32),
+        interpret=interpret,
+    )(*operands)
+    return out[:, :num_groups]
 
 
 @functools.partial(jax.jit, static_argnames=("num_groups", "op", "tn", "tg", "interpret"))
@@ -72,98 +157,38 @@ def seg_agg_pallas(
     tg: int = DEFAULT_TG,
     interpret: bool = False,
 ):
-    """values (N, M) f32, ids (N,) int32, mask (N,) -> (num_groups, M) f32."""
-    n, m = values.shape
-    values = jnp.asarray(values, jnp.float32)
-    ids = jnp.asarray(ids, jnp.int32)
-    mask = jnp.asarray(mask, jnp.float32)
-    tn = min(tn, max(8, n))
-    tg = min(tg, max(8, num_groups))
-    n_pad = (-n) % tn
-    g_pad = (-num_groups) % tg
-    if n_pad:
-        values = jnp.pad(values, ((0, n_pad), (0, 0)))
-        ids = jnp.pad(ids, (0, n_pad))
-        mask = jnp.pad(mask, (0, n_pad))
-    gp = num_groups + g_pad
-    grid = (gp // tg, (n + n_pad) // tn)
-    out = pl.pallas_call(
-        functools.partial(_seg_agg_kernel, op=op, tg=tg),
-        grid=grid,
-        in_specs=[
-            pl.BlockSpec((tn, m), lambda gb, nb: (nb, 0)),
-            pl.BlockSpec((tn, 1), lambda gb, nb: (nb, 0)),
-            pl.BlockSpec((tn, 1), lambda gb, nb: (nb, 0)),
-        ],
-        out_specs=pl.BlockSpec((tg, m), lambda gb, nb: (gb, 0)),
-        out_shape=jax.ShapeDtypeStruct((gp, m), jnp.float32),
-        interpret=interpret,
-    )(values, ids[:, None], mask[:, None])
-    return out[:num_groups]
+    """values (N, M), ids (N,) int32, mask (N,) or None -> (num_groups, M)
+    f32; see ``seg_agg_lanes``."""
+    return seg_agg_lanes(jnp.asarray(values, jnp.float32).T, ids, mask,
+                         num_groups, op, tn, tg, interpret).T
 
 
 # ------------------------------------------------------------- filter-fused
 
 
-def _seg_agg_fused_kernel(values_ref, ids_ref, pred_ref, bounds_ref, out_ref,
-                          *, op: str, tg: int, nk: int, tn: int, n: int):
-    nb = pl.program_id(1)
-
-    @pl.when(nb == 0)
-    def _init():
-        if op == "sum":
-            out_ref[...] = jnp.zeros_like(out_ref)
-        elif op == "min":
-            out_ref[...] = jnp.full_like(out_ref, jnp.inf)
-        else:
-            out_ref[...] = jnp.full_like(out_ref, -jnp.inf)
-
-    gb = pl.program_id(0)
-    values = values_ref[...]  # (TN, M)
-    ids = ids_ref[...][:, 0]  # (TN,)
-    pred = pred_ref[...]  # (TN, P)
-    bounds = bounds_ref[...]  # (P, 2K): [:, :K] = lo, [:, K:] = hi
-    p = pred.shape[1]
+def _seg_agg_fused_kernel(bounds_ref, values_ref, ids_ref, pred_ref, out_ref,
+                          *, op: str, tg: int, nk: int, n: int):
+    tn = values_ref.shape[1]
+    pred = pred_ref[...]  # (P, TN)
     # build the predicate mask inside the tile (no HBM mask round-trip):
-    # AND over predicates of OR over that predicate's [lo, hi] ranges
-    # (NaN-sentinel ranges match NaN values, see ref.bounds_mask_ref).
-    # Static unrolled loops — P and K are small (dashboard filters).
-    # N-padding rows are cut by the global row-index guard.
-    mask = (nb * tn + jax.lax.broadcasted_iota(jnp.int32, (tn,), 0)) < n
-    for j in range(p):
-        x = pred[:, j]
+    # AND over predicates of OR over that predicate's [lo, hi] ranges, the
+    # bounds read as SMEM scalars (NaN-sentinel ranges match NaN values, see
+    # ref.bounds_mask_ref).  Static unrolled loops — P and K are small
+    # (dashboard filters).  Rows past N are cut by the row-index guard.
+    mask = _row_guard(tn, n)
+    for j in range(pred.shape[0]):
+        x = pred[j:j + 1, :]
+        x_nan = jnp.isnan(x)
         mj = None
         for k in range(nk):
-            lo, hi = bounds[j, k], bounds[j, nk + k]
-            within = ((x >= lo) & (x <= hi)) | (jnp.isnan(x) & jnp.isnan(lo))
+            lo = bounds_ref[j * 2 * nk + k]
+            hi = bounds_ref[j * 2 * nk + nk + k]
+            lo_v = jnp.full(x.shape, lo, jnp.float32)
+            within = ((x >= lo_v) & (x <= hi)) | (x_nan & jnp.isnan(lo_v))
             mj = within if mj is None else (mj | within)
         mask = mask & mj
-    local = ids - gb * tg
-    onehot = (local[:, None] == jax.lax.broadcasted_iota(jnp.int32, (tn, tg), 1)) & mask[:, None]
-    if op == "sum":
-        # NaN-safe accumulate: a NaN anywhere in the tile would poison every
-        # group through 0 * NaN in the matmul, so reduce cleaned values and
-        # route NaNs to exactly the groups whose qualifying rows carry them
-        # (second matmul is ~free: the kernel is memory-bound)
-        finite = ~jnp.isnan(values)
-        vals = jnp.where(mask[:, None] & finite, values, 0.0)
-        nan_ind = (mask[:, None] & ~finite).astype(jnp.float32)
-        oh = onehot.astype(jnp.float32)
-        acc = jax.lax.dot_general(
-            oh, vals, (((0,), (0,)), ((), ())), preferred_element_type=jnp.float32
-        )
-        hits = jax.lax.dot_general(
-            oh, nan_ind, (((0,), (0,)), ((), ())), preferred_element_type=jnp.float32
-        )
-        out_ref[...] += acc + jnp.where(hits > 0, jnp.nan, 0.0)
-    else:
-        ident = jnp.inf if op == "min" else -jnp.inf
-        m = values.shape[1]
-        for j in range(m):
-            vj = jnp.where(onehot, values[:, j][:, None], ident)  # (TN, TG)
-            red = jnp.min(vj, axis=0) if op == "min" else jnp.max(vj, axis=0)
-            cur = out_ref[:, j]
-            out_ref[:, j] = jnp.minimum(cur, red) if op == "min" else jnp.maximum(cur, red)
+    _accumulate(out_ref, values_ref[...].astype(jnp.float32), ids_ref[...],
+                mask, op=op, tg=tg)
 
 
 @functools.partial(jax.jit, static_argnames=("num_groups", "op", "tn", "tg", "interpret"))
@@ -180,7 +205,7 @@ def seg_agg_fused_pallas(
 ):
     """Filter-fused grouped aggregation.
 
-    values (N, M) f32, ids (N,) int32, pred_cols (N, P) f32,
+    values (N, M) f32, ids (N,) int32, pred_cols (N, P) f32 with P >= 1,
     bounds (P, 2K) f32 ([:, :K] lo / [:, K:] hi inclusive range pairs, OR
     within a predicate, AND across predicates) -> (num_groups, M) f32.
 
@@ -191,32 +216,24 @@ def seg_agg_fused_pallas(
     n, m = values.shape
     p = pred_cols.shape[1]
     nk = bounds.shape[1] // 2
-    values = jnp.asarray(values, jnp.float32)
-    ids = jnp.asarray(ids, jnp.int32)
-    pred_cols = jnp.asarray(pred_cols, jnp.float32)
-    bounds = jnp.asarray(bounds, jnp.float32)
-    tn = min(tn, max(8, n))
-    tg = min(tg, max(8, num_groups))
-    n_pad = (-n) % tn
-    g_pad = (-num_groups) % tg
-    if n_pad:
-        # pad rows are cut in-tile by the global row-index guard
-        values = jnp.pad(values, ((0, n_pad), (0, 0)))
-        ids = jnp.pad(ids, (0, n_pad))
-        pred_cols = jnp.pad(pred_cols, ((0, n_pad), (0, 0)))
-    gp = num_groups + g_pad
-    grid = (gp // tg, (n + n_pad) // tn)
+    tn, tg, gp = _tiles(n, num_groups, tn, tg)
+    vt = _row_pad(jnp.asarray(values, jnp.float32).T, n, tn)
+    ids2 = _row_pad(jnp.asarray(ids, jnp.int32)[None, :], n, tn)
+    pt = _row_pad(jnp.asarray(pred_cols, jnp.float32).T, n, tn)
+    flat = jnp.asarray(bounds, jnp.float32).reshape(p * 2 * nk)
     out = pl.pallas_call(
-        functools.partial(_seg_agg_fused_kernel, op=op, tg=tg, nk=nk, tn=tn, n=n),
-        grid=grid,
-        in_specs=[
-            pl.BlockSpec((tn, m), lambda gb, nb: (nb, 0)),
-            pl.BlockSpec((tn, 1), lambda gb, nb: (nb, 0)),
-            pl.BlockSpec((tn, p), lambda gb, nb: (nb, 0)),
-            pl.BlockSpec((p, 2 * nk), lambda gb, nb: (0, 0)),
-        ],
-        out_specs=pl.BlockSpec((tg, m), lambda gb, nb: (gb, 0)),
-        out_shape=jax.ShapeDtypeStruct((gp, m), jnp.float32),
+        functools.partial(_seg_agg_fused_kernel, op=op, tg=tg, nk=nk, n=n),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=1,
+            grid=(gp // tg, pl.cdiv(vt.shape[1], tn)),
+            in_specs=[
+                pl.BlockSpec((m, tn), lambda gb, nb, b: (0, nb)),
+                pl.BlockSpec((1, tn), lambda gb, nb, b: (0, nb)),
+                pl.BlockSpec((p, tn), lambda gb, nb, b: (0, nb)),
+            ],
+            out_specs=pl.BlockSpec((m, tg), lambda gb, nb, b: (0, gb)),
+        ),
+        out_shape=jax.ShapeDtypeStruct((m, gp), jnp.float32),
         interpret=interpret,
-    )(values, ids[:, None], pred_cols, bounds)
-    return out[:num_groups]
+    )(flat, vt, ids2, pt)
+    return out[:, :num_groups].T

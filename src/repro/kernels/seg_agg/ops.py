@@ -7,7 +7,7 @@ Implementation selection (shared convention for all kernels in this repo):
 * ``REPRO_KERNELS=xla``        — pure-jnp reference (XLA lowering),
 * unset                        — pallas on TPU, xla elsewhere.
 
-Three entry points:
+Entry points:
 
 * ``seg_agg``        — plain (N, M) grouped aggregation with an explicit mask
   (the seed per-measure path keeps using this);
@@ -21,35 +21,39 @@ Three entry points:
   planner's entry point).
 
 Every dispatcher call counts as one kernel launch in a module-level probe
-(``launch_count``/``reset_launch_count``) so tests can assert the executor's
-single-launch property.  The multi-pod dry-run lowers the XLA path; kernels
-are validated against ref.py in interpret mode by the test suite.
+(``launch_count``/``reset_launch_count``), kept per entry point, so tests can
+assert the executor's single-launch property and a run on the chip can show
+which entry points it drove.  Kernels are validated against ref.py in
+interpret mode by the test suite and compiled for a described v5e by
+``tests/test_tpu_compile.py``.
 """
 from __future__ import annotations
 
+import collections
 import functools
 import os
 
 import jax
 import jax.numpy as jnp
 
-from .kernel import seg_agg_fused_pallas, seg_agg_pallas
-from .ref import bounds_mask_ref, seg_agg_fused_ref, seg_agg_ref
+from .kernel import seg_agg_fused_pallas, seg_agg_lanes, seg_agg_pallas
+from .ref import IDENTITY, bounds_mask_ref, seg_agg_fused_ref, seg_agg_ref
 
-_LAUNCHES = {"n": 0}
+_LAUNCHES: collections.Counter = collections.Counter()
 
 
-def launch_count() -> int:
-    """Number of seg_agg dispatcher calls since the last reset (test probe)."""
-    return _LAUNCHES["n"]
+def launch_count(entry: str | None = None) -> int:
+    """Kernel launches since the last reset: all of them, or those of one
+    entry point (``'seg_agg_fused'``, ...)."""
+    return _LAUNCHES[entry] if entry else sum(_LAUNCHES.values())
 
 
 def reset_launch_count() -> None:
-    _LAUNCHES["n"] = 0
+    _LAUNCHES.clear()
 
 
-def _record_launch() -> None:
-    _LAUNCHES["n"] += 1
+def _record_launch(entry: str) -> None:
+    _LAUNCHES[entry] += 1
 
 
 def kernel_impl() -> str:
@@ -62,7 +66,7 @@ def kernel_impl() -> str:
 def seg_agg(values, ids, mask, num_groups: int, op: str = "sum", impl: str | None = None):
     """Grouped aggregation: (N, M) values + (N,) ids -> (num_groups, M)."""
     impl = impl or kernel_impl()
-    _record_launch()
+    _record_launch("seg_agg")
     if impl == "xla":
         return seg_agg_ref(values, ids, mask, num_groups, op)
     return seg_agg_pallas(
@@ -73,19 +77,6 @@ def seg_agg(values, ids, mask, num_groups: int, op: str = "sum", impl: str | Non
 @functools.partial(jax.jit, static_argnames=("num_groups", "op"))
 def _fused_ref_jit(values, ids, pred_cols, bounds, num_groups, op):
     return seg_agg_fused_ref(values, ids, pred_cols, bounds, num_groups, op)
-
-
-def _pallas_nan_safe_sum(v, ids, num_groups, interpret):
-    """NaN-safe all-rows sum on the plain Pallas kernel: its one-hot matmul
-    spreads any NaN across the whole group tile (0 * NaN), so reduce cleaned
-    values and NaN indicators side by side in one launch, then re-poison
-    exactly the groups whose rows carried NaNs."""
-    m = v.shape[1]
-    nan = jnp.isnan(v)
-    stacked = jnp.concatenate([jnp.where(nan, 0.0, v), nan.astype(jnp.float32)], axis=1)
-    ones = jnp.ones(v.shape[0], jnp.float32)
-    both = seg_agg_pallas(stacked, ids, ones, num_groups, "sum", interpret=interpret)
-    return both[:, :m] + jnp.where(both[:, m:] > 0, jnp.nan, 0.0)
 
 
 def _rect_reduce(values, mask, rect_idx, op):
@@ -122,7 +113,7 @@ def seg_agg_fused(values, ids, pred_cols, bounds, num_groups: int,
     of scatter-based (much faster on CPU backends).
     """
     impl = impl or kernel_impl()
-    _record_launch()
+    _record_launch("seg_agg_fused")
     p = int(bounds.shape[0])
     if impl == "xla":
         b = jnp.asarray(bounds, jnp.float32)
@@ -130,23 +121,12 @@ def seg_agg_fused(values, ids, pred_cols, bounds, num_groups: int,
             return _fused_rect_jit(values, pred_cols, b, rect_idx, op)
         return _fused_ref_jit(values, ids, pred_cols, b, num_groups, op)
     if p == 0:
-        interp = impl == "interpret"
-        if op == "sum":
-            return _p0_sum_jit(jnp.asarray(values, jnp.float32),
-                               jnp.asarray(ids, jnp.int32), num_groups, interp)
-        # min/max select through the one-hot: NaNs stay in their own group
-        ones = jnp.ones(values.shape[0], jnp.float32)
-        return seg_agg_pallas(values, ids, ones, num_groups, op,
-                              interpret=interp)
+        return seg_agg_pallas(values, ids, None, num_groups, op,
+                              interpret=(impl == "interpret"))
     b = jnp.asarray(bounds, jnp.float32)
     flat = jnp.concatenate([b[:, :, 0], b[:, :, 1]], axis=1)  # (P, 2K)
     return seg_agg_fused_pallas(values, ids, pred_cols, flat, num_groups, op,
                                 interpret=(impl == "interpret"))
-
-
-@functools.partial(jax.jit, static_argnames=("num_groups", "interpret"))
-def _p0_sum_jit(values, ids, num_groups, interpret):
-    return _pallas_nan_safe_sum(values, ids, num_groups, interpret)
 
 
 # unrolled per-group GEMM below this many groups; einsum (one fused
@@ -219,23 +199,15 @@ def _masked_rect_jit(values, mask, rect_idx, op):
     return _rect_reduce(jnp.asarray(values, jnp.float32), mask > 0.5, rect_idx, op)
 
 
-@functools.partial(jax.jit, static_argnames=("num_groups", "op", "impl"))
-def _masked_jit(values, ids, mask, num_groups, op, impl):
-    values = jnp.asarray(values, jnp.float32)
-    sel = mask > 0.5
-    if op == "sum":
-        v = jnp.where(sel[:, None], values, 0.0)
-        if impl == "xla":
-            return jax.ops.segment_sum(v, ids, num_segments=num_groups)
-        return _pallas_nan_safe_sum(v, ids, num_groups, impl == "interpret")
-    ident = jnp.inf if op == "min" else -jnp.inf
-    v = jnp.where(sel[:, None], values, ident)
-    if impl == "xla":
-        seg = jax.ops.segment_min if op == "min" else jax.ops.segment_max
-        return seg(v, ids, num_segments=num_groups)
-    ones = jnp.ones(values.shape[0], jnp.float32)
-    return seg_agg_pallas(v, ids, ones, num_groups, op,
-                          interpret=(impl == "interpret"))
+_SEGMENT = {"sum": jax.ops.segment_sum, "min": jax.ops.segment_min,
+            "max": jax.ops.segment_max}
+
+
+@functools.partial(jax.jit, static_argnames=("num_groups", "op"))
+def _masked_xla_jit(values, ids, mask, num_groups, op):
+    v = jnp.where((mask > 0.5)[:, None], jnp.asarray(values, jnp.float32),
+                  IDENTITY[op])
+    return _SEGMENT[op](v, ids, num_segments=num_groups)
 
 
 def seg_agg_masked(values, ids, mask, num_groups: int, op: str = "sum",
@@ -249,38 +221,39 @@ def seg_agg_masked(values, ids, mask, num_groups: int, op: str = "sum",
     range) but the aggregation should stay fused and device-side.
     """
     impl = impl or kernel_impl()
-    _record_launch()
+    _record_launch("seg_agg_masked")
     mask = jnp.asarray(mask, jnp.float32)
-    if impl == "xla" and rect_idx is not None:
+    if impl != "xla":
+        # the kernel drops masked-out rows (op identity) and is NaN-safe
+        return seg_agg_pallas(values, ids, mask, num_groups, op,
+                              interpret=(impl == "interpret"))
+    if rect_idx is not None:
         return _masked_rect_jit(values, mask, rect_idx, op)
-    return _masked_jit(values, ids, mask, num_groups, op, impl)
+    return _masked_xla_jit(values, ids, mask, num_groups, op)
 
 
 @functools.partial(jax.jit, static_argnames=("num_groups", "op", "impl"))
 def _batch_jit(values, ids, pred_cols, bounds, num_groups, op, impl):
     s = bounds.shape[0]
     n, m = values.shape
+    values = jnp.asarray(values, jnp.float32)
     # one vmapped bounds pass (as in _rect_batch_masks) instead of unrolling
     # S copies of the mask computation into the program
-    masks = jax.vmap(lambda b: bounds_mask_ref(pred_cols, b))(bounds).T  # (N, S)
-    if op == "sum":
-        v = jnp.where(masks[:, :, None], values[:, None, :], 0.0)
-    else:
-        ident = jnp.inf if op == "min" else -jnp.inf
-        v = jnp.where(masks[:, :, None], values[:, None, :], ident)
-    v = v.reshape(n, s * m)
+    masks = jax.vmap(lambda b: bounds_mask_ref(pred_cols, b))(bounds)  # (S, N)
     if impl == "xla":
-        seg = {"sum": jax.ops.segment_sum, "min": jax.ops.segment_min,
-               "max": jax.ops.segment_max}[op]
-        out = seg(v, ids, num_segments=num_groups)
-    elif op == "sum":
-        out = _pallas_nan_safe_sum(v, ids, num_groups, impl == "interpret")
-    else:
-        # min/max select through the one-hot, so NaNs stay in their own group
-        ones = jnp.ones(n, jnp.float32)
-        out = seg_agg_pallas(v, ids, ones, num_groups, op,
-                             interpret=(impl == "interpret"))
-    return out.reshape(num_groups, s, m).transpose(1, 0, 2)
+        v = jnp.where(masks.T[:, :, None], values[:, None, :], IDENTITY[op])
+        out = _SEGMENT[op](v.reshape(n, s * m), ids, num_segments=num_groups)
+        return out.reshape(num_groups, s, m).transpose(1, 0, 2)
+    # lane-major (S*M, N) block: masked-out rows hold the op identity; the
+    # kernel keeps NaNs of selected rows in their own group.  Concatenating
+    # S (M, N) slabs writes the block in the kernel's layout; a reshape of
+    # an (S, M, N) select instead costs a relayout copy of the whole block.
+    vt = values.T
+    v = jnp.concatenate([jnp.where(masks[k][None, :], vt, IDENTITY[op])
+                         for k in range(s)], axis=0)
+    out = seg_agg_lanes(v, ids, None, num_groups, op,
+                        interpret=(impl == "interpret"))  # (S*M, G)
+    return out.reshape(s, m, num_groups).transpose(0, 2, 1)
 
 
 def seg_agg_batch(values, ids, pred_cols, bounds, num_groups: int,
@@ -295,7 +268,7 @@ def seg_agg_batch(values, ids, pred_cols, bounds, num_groups: int,
     ``seg_agg_fused``).  ``rect_idx`` as in ``seg_agg_fused``.
     """
     impl = impl or kernel_impl()
-    _record_launch()
+    _record_launch("seg_agg_batch")
     if impl == "xla" and rect_idx is not None:
         return _batch_rect_jit(values, jnp.asarray(pred_cols, jnp.float32),
                                jnp.asarray(bounds, jnp.float32), rect_idx, op)
@@ -319,7 +292,7 @@ def seg_agg_batch_blocks(sum_block, mm_block, ids, pred_cols, bounds,
     one kernel per block and record launches accordingly.
     """
     impl = impl or kernel_impl()
-    _record_launch()
+    _record_launch("seg_agg_batch_blocks")
     pred_cols = jnp.asarray(pred_cols, jnp.float32)
     b = jnp.asarray(bounds, jnp.float32)
     if impl == "xla" and rect_idx is not None:
@@ -329,6 +302,7 @@ def seg_agg_batch_blocks(sum_block, mm_block, ids, pred_cols, bounds,
     sums = _batch_jit(sum_block, ids, pred_cols, b, num_groups, "sum", impl)
     mm = None
     if mm_block is not None:
-        _record_launch()  # second kernel dispatch on the per-block fallback
+        # second kernel dispatch on the per-block fallback
+        _record_launch("seg_agg_batch_blocks")
         mm = _batch_jit(mm_block, ids, pred_cols, b, num_groups, "min", impl)
     return sums, mm

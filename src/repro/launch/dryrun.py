@@ -1,7 +1,3 @@
-import os
-
-os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=512"
-
 """Multi-pod dry-run: lower + compile every (arch x shape x mesh) cell.
 
 Proves the distribution config is coherent without hardware: builds the
@@ -14,24 +10,32 @@ Usage:
     python -m repro.launch.dryrun --arch qwen3-32b --shape train_4k --mesh single
     python -m repro.launch.dryrun --all --out results/dryrun.json
 """
-import argparse  # noqa: E402
-import json  # noqa: E402
-import re  # noqa: E402
-import time  # noqa: E402
-import traceback  # noqa: E402
+import argparse
+import json
+import os
+import re
+import time
+import traceback
 
-import jax  # noqa: E402
-import jax.numpy as jnp  # noqa: E402
-from jax.sharding import NamedSharding, PartitionSpec as P  # noqa: E402
+import jax
+import jax.numpy as jnp
+from jax.sharding import NamedSharding, PartitionSpec as P
 
-from ..configs.registry import ASSIGNED, SUBQUADRATIC, get  # noqa: E402
-from ..configs.shapes import SHAPES, input_specs, sds  # noqa: E402
-from ..distributed.sharding import (  # noqa: E402
+from ..configs.registry import ASSIGNED, SUBQUADRATIC, get
+from ..configs.shapes import SHAPES, input_specs, sds
+from ..distributed.sharding import (
     batch_axes, sharding_hints, tree_param_specs,
 )
-from ..models.model import ModelConfig, shapes_to_struct  # noqa: E402
-from ..training.optimizer import AdamWConfig, adamw_update, init_opt_state, opt_state_specs  # noqa: E402
-from .mesh import make_production_mesh  # noqa: E402
+from ..models.model import ModelConfig, shapes_to_struct
+from ..training.optimizer import AdamWConfig, adamw_update, init_opt_state, opt_state_specs
+from .mesh import make_production_mesh
+
+
+def force_placeholder_devices() -> None:
+    """Give the CPU backend 512 placeholder devices for the production mesh.
+    Must run before JAX initializes a backend, so launchers call it first;
+    importing this module changes nothing."""
+    os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=512"
 
 # ------------------------------------------------------- collective parsing
 
@@ -349,6 +353,7 @@ def main():
                     help="baseline | kv_seq_shard | no_zero1 | zero3_params | *_sp")
     ap.add_argument("--force", action="store_true")
     args = ap.parse_args()
+    force_placeholder_devices()
 
     os.makedirs(os.path.dirname(args.out) or ".", exist_ok=True)
     results = {}

@@ -4,7 +4,8 @@ Boots a workload (schema + data + OLAP backend), a canonicalizer LLM served
 by our engine (optionally restored from a training checkpoint), and the
 semantic cache middleware — then replays a query stream and reports cache
 statistics.  ``--simulated-llm`` swaps in the calibrated SimulatedLLM
-(no model inference), which is what the paper-table benchmarks use.
+(no model inference), which is what the paper-table benchmarks use.  Exits
+non-zero when any request ends with status ``error``.
 
 Usage:
     python -m repro.launch.serve --workload ssb --queries 100 --simulated-llm
@@ -13,6 +14,7 @@ Usage:
 from __future__ import annotations
 
 import argparse
+import sys
 
 
 def main():
@@ -33,6 +35,9 @@ def main():
 
     import jax
 
+    from .compile_cache import enable_compilation_cache
+
+    enable_compilation_cache()
     from ..core import MemoizedNL, SafetyPolicy, SemanticCache, SimulatedLLM
     from ..olap.executor import OlapExecutor
     from ..service import CacheService, QueryRequest
@@ -74,8 +79,9 @@ def main():
     # backend scan and identical in-flight intents are deduped
     reqs = [QueryRequest(sql=q.text, tenant=args.workload) if q.kind == "sql"
             else QueryRequest(nl=q.text, tenant=args.workload) for q in stream]
+    results = []
     for i in range(0, len(reqs), args.batch):
-        svc.submit_batch(reqs[i:i + args.batch])
+        results += svc.submit_batch(reqs[i:i + args.batch])
     s = cache.stats
     n = len(stream)
     print(f"[serve] {n} queries (batch={args.batch}) | hit rate {s.hit_rate:.3f} "
@@ -86,7 +92,11 @@ def main():
           f"| deduped {tenant.stats.deduped_misses} "
           f"| backend execs {backend.executions} "
           f"| rows scanned {backend.rows_scanned:,}")
+    errors = [r for r in results if r.status == "error"]
+    for r in errors[:5]:
+        print(f"[serve] error: {r.error}", file=sys.stderr)
+    return 1 if errors else 0
 
 
 if __name__ == "__main__":
-    main()
+    sys.exit(main())

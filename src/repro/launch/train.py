@@ -30,8 +30,9 @@ def main():
     args = ap.parse_args()
 
     if args.dryrun_mesh:
-        from .dryrun import run_cell
+        from .dryrun import force_placeholder_devices, run_cell
 
+        force_placeholder_devices()
         res = run_cell(args.arch, "train_4k", args.dryrun_mesh)
         print(res)
         return

@@ -57,7 +57,7 @@ from ..core.sqlparse import SQLSyntaxError, UnsupportedQuery
 from ..core.table import ResultTable
 from ..obs.trace import adopt, child_span, current_ctx
 from ..resilience import faults
-from ..kernels.seg_agg.ops import (seg_agg, seg_agg_batch_blocks,
+from ..kernels.seg_agg.ops import (kernel_impl, seg_agg, seg_agg_batch_blocks,
                                    seg_agg_fused, seg_agg_masked)
 from . import scan_plane
 from .columnar import Dataset, date_to_days
@@ -384,11 +384,11 @@ class OlapExecutor:
             levels = [self._level_plan(lv) for lv in lvls]
             gids_np, n_groups, sparse_uniq = self._group_ids(levels)
             gids_dev = self._device_gids(lvls, gids_np)
-            rect = self._rect_index(lvls, gids_np, n_groups)
+            impl = self._kernel_impl()
+            rect = self._rect_index(lvls, gids_np, n_groups, impl)
             plan = self._measure_plan(measures)
             group_sigs = [sigs[i] for i in idxs]
             pred_block, bounds = self._batch_predicates(group_sigs)
-            impl = None if self.impl == "auto" else self.impl
             sums_dev, mms_dev = seg_agg_batch_blocks(
                 plan.sum_block, plan.minmax_block, gids_dev, pred_block,
                 bounds, n_groups, impl=impl, rect_idx=rect)
@@ -449,13 +449,10 @@ class OlapExecutor:
         if self._devices is _UNSET:
             devs = None
             if self.fused:
-                try:
-                    import jax
+                import jax
 
-                    local = jax.local_devices()
-                    devs = local if len(local) > 1 else None
-                except Exception:
-                    devs = None
+                local = jax.local_devices()
+                devs = local if len(local) > 1 else None
             self._devices = devs
         return self._devices
 
@@ -552,13 +549,14 @@ class OlapExecutor:
         for k in range(len(chunks)):
             stager = None
             next_sub = None
+            staged_errors: list[BaseException] = []
             if k + 1 < len(chunks):
                 # double buffer: stage chunk k+1's device arrays while
                 # chunk k scans
                 next_sub = self._chunk_sub(chunks[k + 1], dev, resident=False)
                 stager = _threading.Thread(
-                    target=self._prestage, args=(next_sub, psigs, dev),
-                    daemon=True)
+                    target=self._prestage,
+                    args=(next_sub, psigs, dev, staged_errors), daemon=True)
                 stager.start()
             with sub._scan_mutex:
                 before = (sub.executions, sub.rows_scanned, sub.batch_groups)
@@ -574,6 +572,8 @@ class OlapExecutor:
                 self._release_chunk(sub)
             if stager is not None:
                 stager.join()
+                if staged_errors:
+                    raise staged_errors[0]
             if next_sub is not None:
                 sub = next_sub
         return [tl[0] if len(tl) == 1 else merge_partials(ps, tl)
@@ -637,11 +637,13 @@ class OlapExecutor:
                 st["batch_groups"] += groups
                 st["chunks"] += 1
 
-    def _prestage(self, sub: "OlapExecutor", psigs, dev) -> None:
+    def _prestage(self, sub: "OlapExecutor", psigs, dev,
+                  errors: list) -> None:
         """Stager thread body: force the next chunk's fact-column uploads
         (level alignments, measure expressions, predicate columns) while the
-        current chunk scans.  Advisory — any failure falls through to the
-        scan's own lazy build."""
+        current chunk scans.  A failure (a device out of memory, a lost
+        device) is kept in ``errors`` and raised by the scanning thread after
+        ``join``, so it fails the scan instead of vanishing."""
         try:
             if dev is not None:
                 import jax
@@ -650,8 +652,8 @@ class OlapExecutor:
                     self._stage_arrays(sub, psigs)
             else:
                 self._stage_arrays(sub, psigs)
-        except Exception:
-            pass
+        except Exception as e:
+            errors.append(e)
 
     def _stage_arrays(self, sub: "OlapExecutor", psigs) -> None:
         if not sub.fused:
@@ -687,9 +689,9 @@ class OlapExecutor:
         levels = [self._level_plan(lv) for lv in sig.levels]
         gids_np, n_groups, sparse_uniq = self._group_ids(levels)
         gids_dev = self._device_gids(sig.levels, gids_np)
-        rect = self._rect_index(sig.levels, gids_np, n_groups)
+        impl = self._kernel_impl()
+        rect = self._rect_index(sig.levels, gids_np, n_groups, impl)
         plan = self._measure_plan(sig.measures)
-        impl = None if self.impl == "auto" else self.impl
         enc = self._predicate_plan(sig)
         if enc is None:
             # some predicate can't be evaluated exactly in f32: build the
@@ -776,12 +778,20 @@ class OlapExecutor:
     _RECT_MIN_CELLS = 1 << 16  # always allow tiny group spaces
     _RECT_MAX_CELLS = 1 << 25
 
-    def _rect_index(self, levels_key: tuple, gids_np: np.ndarray, n_groups: int):
+    def _kernel_impl(self) -> str:
+        """The seg_agg impl this executor's device scans dispatch to."""
+        return kernel_impl() if self.impl == "auto" else self.impl
+
+    def _rect_index(self, levels_key: tuple, gids_np: np.ndarray, n_groups: int,
+                    impl: str):
         """Cached (n_groups, R) row-index rectangle for a level combination:
         row g lists the fact rows of group g, padded with the out-of-range
         index N.  Lets the XLA path reduce with a vectorized gather instead
         of a serial scatter; None when group sizes are too skewed (padding
-        blowup) or the padded matrix would be too large."""
+        blowup) or the padded matrix would be too large, and for the Pallas
+        impls, which never read it."""
+        if impl != "xla":
+            return None
         key = ("rectidx", levels_key)
         if key in self._rect_cache:
             return self._rect_cache[key]

@@ -39,6 +39,41 @@ def test_seg_agg_dtypes():
         np.testing.assert_allclose(out, ref, rtol=1e-2, atol=1e-2)
 
 
+def _identity_masked_ref(vals, ids, mask, g, op):
+    """Masked-out rows hold the op identity (NaN-safe contract): only NaNs
+    of selected rows reach a group."""
+    from repro.kernels.seg_agg.ref import IDENTITY, seg_agg_ref
+
+    v = np.where(mask[:, None] > 0.5, vals, np.float32(IDENTITY[op]))
+    return np.asarray(seg_agg_ref(v, ids, np.ones(len(ids), np.float32), g, op))
+
+
+@pytest.mark.parametrize("n,m,g,tn,tg", [
+    (1000, 3, 17, 128, 128),     # N not a multiple of the row tile
+    (2500, 2, 1000, 1024, 512),  # G > TG, partial last row tile
+    (300, 1, 130, 128, 128),     # G just over one group tile
+    (96, 4, 5, 1024, 512),       # N below one row tile
+])
+@pytest.mark.parametrize("op", ["sum", "min", "max"])
+@pytest.mark.parametrize("with_mask", [True, False])
+def test_seg_agg_lane_layout(n, m, g, tn, tg, op, with_mask):
+    """Lane-major plain kernel: partial row tiles, several group tiles, NaNs
+    in masked-in and masked-out rows, and the mask-free form."""
+    from repro.kernels.seg_agg.kernel import seg_agg_pallas
+
+    vals = rng.normal(size=(n, m)).astype(np.float32)
+    vals[rng.random((n, m)) < 0.03] = np.nan
+    ids = rng.integers(0, g, size=n).astype(np.int32)
+    mask = (rng.random(n) > 0.3).astype(np.float32) if with_mask \
+        else np.ones(n, np.float32)
+    vals[:2, 0] = np.nan  # row 0 selected; row 1 masked out when masking
+    mask[0], mask[1] = 1.0, 0.0 if with_mask else 1.0
+    ref = _identity_masked_ref(vals, ids, mask, g, op)
+    out = np.asarray(seg_agg_pallas(vals, ids, mask if with_mask else None, g, op,
+                                    tn=tn, tg=tg, interpret=True))
+    np.testing.assert_allclose(out, ref, rtol=1e-5, atol=1e-5)
+
+
 # ------------------------------------------------------ seg_agg filter-fused
 
 
@@ -70,6 +105,55 @@ def test_seg_agg_fused(n, m, g, p, k, op):
     ref = np.asarray(seg_agg_fused_ref(vals, ids, pred, bounds, g, op))
     flat = np.concatenate([bounds[:, :, 0], bounds[:, :, 1]], axis=1)
     out = np.asarray(seg_agg_fused_pallas(vals, ids, pred, flat, g, op, interpret=True))
+    np.testing.assert_allclose(out, ref, rtol=1e-5, atol=1e-5)
+
+
+@pytest.mark.parametrize("n,m,g,p,k,tn,tg", [
+    (1000, 3, 17, 1, 3, 128, 128),     # K > 1, partial row tile
+    (2500, 2, 700, 3, 2, 1024, 512),   # G > TG, P = 3
+    (700, 1, 9, 2, 1, 256, 128),
+])
+@pytest.mark.parametrize("op", ["sum", "min", "max"])
+def test_seg_agg_fused_lane_layout(n, m, g, p, k, tn, tg, op):
+    """Lane-major filter-fused kernel with its bounds in SMEM: partial row
+    tiles, several group tiles, K ranges per predicate, NaNs in selected and
+    in filtered-out rows."""
+    from repro.kernels.seg_agg.kernel import seg_agg_fused_pallas
+    from repro.kernels.seg_agg.ref import bounds_mask_ref, seg_agg_fused_ref
+
+    vals = rng.normal(size=(n, m)).astype(np.float32)
+    vals[rng.random((n, m)) < 0.03] = np.nan
+    ids = rng.integers(0, g, size=n).astype(np.int32)
+    pred = rng.integers(0, 10, size=(n, p)).astype(np.float32)
+    bounds = _rand_bounds(p, k)
+    pred[0] = bounds[:, 0, 0]  # row 0 passes every predicate
+    pred[1, 0] = 99.0  # row 1 fails the first
+    vals[:2, 0] = np.nan
+    sel = np.asarray(bounds_mask_ref(pred, bounds))
+    assert sel[0] and not sel[1]
+    ref = np.asarray(seg_agg_fused_ref(vals, ids, pred, bounds, g, op))
+    flat = np.concatenate([bounds[:, :, 0], bounds[:, :, 1]], axis=1)
+    out = np.asarray(seg_agg_fused_pallas(vals, ids, pred, flat, g, op, tn=tn,
+                                          tg=tg, interpret=True))
+    np.testing.assert_allclose(out, ref, rtol=1e-5, atol=1e-5)
+
+
+@pytest.mark.parametrize("op", ["sum", "min", "max"])
+def test_seg_agg_fused_no_predicates(op):
+    """P = 0 through the dispatcher: every row counts, on the mask-free
+    kernel, across a partial last row tile and two group tiles."""
+    from repro.kernels.seg_agg.ops import seg_agg_fused
+    from repro.kernels.seg_agg.ref import seg_agg_fused_ref
+
+    n, m, g = 2500, 2, 600
+    vals = rng.normal(size=(n, m)).astype(np.float32)
+    vals[rng.random((n, m)) < 0.02] = np.nan
+    ids = rng.integers(0, g, size=n).astype(np.int32)
+    pred = np.zeros((n, 0), np.float32)
+    bounds = np.zeros((0, 1, 2), np.float32)
+    ref = np.asarray(seg_agg_fused_ref(vals, ids, pred, bounds, g, op))
+    out = np.asarray(seg_agg_fused(vals, ids, pred, bounds, g, op,
+                                   impl="interpret"))
     np.testing.assert_allclose(out, ref, rtol=1e-5, atol=1e-5)
 
 

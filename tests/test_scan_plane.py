@@ -245,6 +245,31 @@ class TestPartitionedExecutor:
         assert st["streaming_chunks"] > 0
         assert all(p["chunks"] > 0 for p in st["per_partition"])
 
+    def test_stager_failure_fails_the_scan(self, ssb_small, monkeypatch):
+        """A failed pre-stage of the next chunk (device OOM, lost device)
+        is raised by the scan, not swallowed."""
+        sig = SQLCanonicalizer(ssb_small.schema).canonicalize(
+            ssb_small.intents[0].sql)
+        exs = OlapExecutor(ssb_small.dataset, impl="xla", partitions=2,
+                           max_device_rows=700)
+
+        def boom(self, sub, psigs):
+            raise MemoryError("staging failed")
+
+        monkeypatch.setattr(OlapExecutor, "_stage_arrays", boom)
+        with pytest.raises(MemoryError, match="staging failed"):
+            exs.execute(sig)
+
+    @pytest.mark.parametrize("impl,built", [("xla", True), ("interpret", False)])
+    def test_rect_index_only_for_xla(self, ssb_small, impl, built):
+        """The (G, R) rect index is read only by the XLA path: the Pallas
+        impls never pay its host argsort and upload."""
+        sig = SQLCanonicalizer(ssb_small.schema).canonicalize(
+            ssb_small.intents[0].sql)
+        ex = OlapExecutor(ssb_small.dataset, impl=impl)
+        ex.execute(sig)
+        assert (ex.memo_sizes()["rect_index"] > 0) == built
+
     def test_rows_scanned_matches_unpartitioned(self, ssb_small):
         """Partition-edge accounting: the partitioned scan must count each
         fact row exactly once per scan — summed across partitions and chunks

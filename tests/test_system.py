@@ -101,6 +101,28 @@ class TestServingSubstrate:
                 cells += 1
         assert cells == 32  # 10x3 + 2 long-context cells
 
+    def test_compilation_cache_dir(self, monkeypatch, tmp_path):
+        """A set JAX_COMPILATION_CACHE_DIR is left to JAX; otherwise the
+        cache goes to a fixed directory of the checkout."""
+        import os
+
+        import jax
+
+        from repro.launch.compile_cache import enable_compilation_cache
+
+        before = jax.config.jax_compilation_cache_dir
+        monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", str(tmp_path))
+        assert enable_compilation_cache() == str(tmp_path)
+        assert jax.config.jax_compilation_cache_dir == before
+        monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR")
+        checkout = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+        try:
+            path = enable_compilation_cache()
+            assert path == os.path.join(checkout, ".jax_cache")
+            assert jax.config.jax_compilation_cache_dir == path
+        finally:
+            jax.config.update("jax_compilation_cache_dir", before)
+
     def test_dryrun_results_green(self):
         """The committed dry-run artifact must show every baseline cell ok."""
         import json
