@@ -17,6 +17,10 @@ MIN/MAX select through the one-hot on the VPU and reduce over the lane axis
 into rows of the output block.  N is never padded in HBM: the grid runs
 ``cdiv(N, TN)`` row tiles and a global row-index guard drops the rows past N
 in the last, partial tile.
+
+Each kernel is named after its op (``seg_agg_sum``, ``seg_agg_min``,
+``seg_agg_fused_sum``, ...): the name becomes the custom call's HLO
+instruction name, which is how a profiler trace names the op.
 """
 from __future__ import annotations
 
@@ -142,6 +146,7 @@ def seg_agg_lanes(vt, ids, mask, num_groups: int, op: str = "sum",
         out_specs=pl.BlockSpec((m, tg), lambda gb, nb: (0, gb)),
         out_shape=jax.ShapeDtypeStruct((m, gp), jnp.float32),
         interpret=interpret,
+        name=f"seg_agg_{op}",
     )(*operands)
     return out[:, :num_groups]
 
@@ -235,5 +240,6 @@ def seg_agg_fused_pallas(
         ),
         out_shape=jax.ShapeDtypeStruct((m, gp), jnp.float32),
         interpret=interpret,
+        name=f"seg_agg_fused_{op}",
     )(flat, vt, ids2, pt)
     return out[:, :num_groups].T

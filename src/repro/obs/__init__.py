@@ -6,19 +6,25 @@ Three substrates behind one config (:class:`ObsConfig`) and one holder
 * :mod:`.trace` — per-request traces of nested spans with head-based
   sampling, a bounded span ring, an optional JSONL sink, and explicit
   cross-thread context propagation (shard-miss pool, scan-plane partition
-  pool, single-flight leader→follower links, the storage spill worker);
+  pool, single-flight leader→follower links, the storage spill worker),
+  and ``span``: the pipeline's stages and the executor's plan / dispatch /
+  wait / finalize as ``repro.*`` spans in a captured profiler trace, on the
+  device ops' clock;
 * :mod:`.metrics` — typed Counter/Gauge/Histogram instruments with label
   sets and Prometheus-text / JSON exposition (``CacheService.metrics()``);
   the log-bucketed :class:`~.metrics.LogHistogram` also backs
   ``TenantStats.stage_percentiles`` directly;
 * :mod:`.audit` — structured cache-lifecycle events (put / hit /
   derivation-hit / evict / demote / promote / refresh / TTL-expiry /
-  morgue-serve) with policy inputs, queryable via ``python -m repro.obs``.
+  morgue-serve) with policy inputs, queryable via ``python -m repro.obs``;
+* :mod:`.compiles` — the process's XLA compilations, from JAX's monitoring
+  event, mirrored as ``xla_compiles_total`` / ``xla_compile_seconds_total``.
 
 Everything is off the hot path when disabled: an unsampled request pays one
-``is None`` check per stage, an un-audited cache one attribute load per
-lifecycle call site, and metrics are mirrored from the existing counters at
-exposition time rather than double-bumped per request.
+``is None`` check per stage, a batch one profiler check while no profile is
+captured, an un-audited cache one attribute load per lifecycle call site,
+and metrics are mirrored from the existing counters at exposition time
+rather than double-bumped per request.
 
 Future serving-plane endpoints (the async front door on the ROADMAP) must
 export through this registry and propagate trace context through these
@@ -101,23 +107,10 @@ class ObsPlane:
             AuditLog(config.audit_capacity, config.audit_sink)
             if config.audit else None)
 
-    def stats(self) -> dict:
-        d = {"config": dataclasses.asdict(self.config),
-             "tracer": self.tracer.stats()}
-        if self.audit is not None:
-            d["audit"] = self.audit.stats()
-        return d
-
     def close(self) -> None:
         self.tracer.close()
         if self.audit is not None:
             self.audit.close()
-
-
-# A single always-disabled plane shared by tenants whose service predates
-# observability configuration (or standalone pipeline tests): every check
-# against it short-circuits.
-DISABLED_PLANE = ObsPlane(ObsConfig.disabled())
 
 
 # ------------------------------------------------------ completeness check

@@ -102,11 +102,6 @@ class AuditLog:
         return {"emitted": emitted, "ring_len": len(self._ring),
                 "sink": self.sink_path}
 
-    def flush(self) -> None:
-        with self._lock:
-            if self._sink is not None:
-                self._sink.flush()
-
     def close(self) -> None:
         with self._lock:
             if self._sink is not None:

@@ -13,9 +13,9 @@ originating trace id.
 Two recording styles, matching how the pipeline is instrumented:
 
 * ``trace.record(name, ...)`` — after-the-fact span from a measured
-  duration (the per-stage spans are emitted at finalize time from the same
-  ``perf_counter`` timings the pipeline already keeps, so tracing adds no
-  second clock read per stage);
+  duration (the per-stage spans are emitted at finalize time from the
+  ``perf_counter`` starts and timings the pipeline already reads, so
+  tracing adds no second clock read per stage);
 * ``span_ctx(trace, name, ...)`` — a *live* span context manager that also
   publishes itself as the calling thread's current span context, which is
   how cross-thread propagation works: the scan plane's partition pool, the
@@ -25,13 +25,23 @@ Two recording styles, matching how the pipeline is instrumented:
 Context propagation is explicit-capture + thread-local-adopt:
 ``current_ctx()`` reads the calling thread's ``(trace, span_id)`` pair,
 ``adopt(ctx)`` installs one for a worker's body, and ``child_span(name)``
-opens a live span under whatever context is installed (a no-op when none
-is — disabled tracing costs one thread-local read at each fan-out point,
-nothing on the warm-hit path).
+opens a live span under whatever context is installed (only the profiler
+check below when none is — disabled tracing costs one thread-local read at
+each fan-out point, nothing on the warm-hit path).
 
-Locking: ``Tracer._lock`` is a leaf — emission happens under shard locks
-and inside pool threads, and nothing else is ever acquired while holding
-it.
+Independently of sampling, :func:`span` marks where work happens on the
+profiler's own clock: while a profile is being captured
+(``jax.profiler.trace`` / ``start_trace``) it opens a
+``jax.profiler.TraceAnnotation("repro.<name>", **ids)``, so the program's
+host spans line up with the device's ops in the captured trace; with no
+capture it returns a shared no-op after one cached check.  The live spans
+(``span_ctx``/``child_span``) open the same annotation.  Seconds per span
+name over the current capture are kept for readers of the capture
+(:func:`profile_span_seconds`).
+
+Locking: ``Tracer._lock`` and ``_CaptureSpans._lock`` are leaves — emission
+happens under shard locks and inside pool threads, and nothing else is ever
+acquired while holding either.
 """
 from __future__ import annotations
 
@@ -40,14 +50,14 @@ import json
 import threading
 import time
 from collections import deque
-from contextlib import contextmanager
+from contextlib import contextmanager, nullcontext
 from typing import Optional
 
 from ..analysis.sanitizer import make_lock
 
 __all__ = [
     "DEFAULT_SAMPLE_RATE", "Trace", "Tracer", "adopt", "child_span",
-    "current_ctx", "span_ctx",
+    "current_ctx", "profile_span_seconds", "profiling", "span", "span_ctx",
 ]
 
 DEFAULT_SAMPLE_RATE = 0.01  # head-based: 1 in 100 requests fully traced
@@ -182,12 +192,6 @@ class Tracer:
             out = [s for s in out if s["trace"] == trace_id]
         return out
 
-    def trace_ids(self) -> list[str]:
-        seen: dict[str, None] = {}
-        for s in self.spans():
-            seen.setdefault(s["trace"])
-        return list(seen)
-
     def stats(self) -> dict:
         with self._lock:
             return {
@@ -202,17 +206,113 @@ class Tracer:
                 "sink": self.sink_path,
             }
 
-    def flush(self) -> None:
-        with self._lock:
-            if self._sink is not None:
-                self._sink.flush()
-
     def close(self) -> None:
         with self._lock:
             if self._sink is not None:
                 self._sink.flush()
                 self._sink.close()
                 self._sink = None
+
+
+# ------------------------------------------- spans on the profiler's clock
+
+SPAN_PREFIX = "repro."  # the name prefix of every span in a captured trace
+
+# TraceAnnotation and its is_enabled check, bound on first use: jax is
+# imported lazily so obs stays import-light
+_annotation = None
+_is_profiling = None
+
+
+def _bind():
+    global _annotation, _is_profiling
+    from jax.profiler import TraceAnnotation
+
+    _annotation = TraceAnnotation
+    _is_profiling = TraceAnnotation.is_enabled
+    return _is_profiling
+
+
+_NO_SPAN = nullcontext()
+
+
+class _CaptureSpans:
+    """Seconds per span name over the current (or last) profile capture.
+    A span that finds no capture marks the totals stale; the first span of
+    the next capture clears them, so they never mix two captures.  One per
+    process, as the profiler's capture is."""
+
+    def __init__(self):
+        self._lock = make_lock("_CaptureSpans._lock")
+        self._seconds: dict[str, float] = {}  # guarded-by: self._lock
+        # set by spans that find no capture; one flag store, no lock
+        self.stale = True  # guarded-by: external[written only while no capture runs; read under _lock]
+
+    def add(self, name: str, seconds: float) -> None:
+        with self._lock:
+            if self.stale:
+                self._seconds.clear()
+                self.stale = False
+            self._seconds[name] = self._seconds.get(name, 0.0) + seconds
+
+    def seconds(self) -> dict[str, float]:
+        with self._lock:
+            return dict(self._seconds)
+
+
+_CAPTURE = _CaptureSpans()
+
+
+class _ProfiledSpan:
+    __slots__ = ("name", "ids", "_ann", "_prev", "_t0")
+
+    def __init__(self, name: str, ids: dict):
+        self.name = name
+        self.ids = ids
+
+    def __enter__(self):
+        # a nested span carries its enclosing spans' ids (the submit id of
+        # the pipeline stage around an executor span on the same thread)
+        prev = getattr(_tls, "ids", None)
+        ids = {**prev, **self.ids} if prev else self.ids
+        self._prev = prev
+        _tls.ids = ids
+        self._ann = _annotation(SPAN_PREFIX + self.name, **ids)
+        self._ann.__enter__()
+        self._t0 = time.perf_counter()
+        return None
+
+    def __exit__(self, *exc):
+        dt = time.perf_counter() - self._t0
+        self._ann.__exit__(*exc)
+        _tls.ids = self._prev
+        _CAPTURE.add(self.name, dt)
+        return False
+
+
+def profiling() -> bool:
+    """Whether a profile is being captured: the check behind :func:`span`,
+    for callers that take it once for several spans."""
+    if (_is_profiling or _bind())():
+        return True
+    _CAPTURE.stale = True
+    return False
+
+
+def span(name: str, **ids):
+    """A span named ``repro.<name>`` in the profiler's trace, with ``ids``
+    as its metadata, while a profile is being captured; else a shared
+    no-op that constructs nothing."""
+    if (_is_profiling or _bind())():
+        return _ProfiledSpan(name, ids)
+    _CAPTURE.stale = True
+    return _NO_SPAN
+
+
+def profile_span_seconds() -> dict[str, float]:
+    """Seconds per span name (without the prefix), summed over threads, of
+    the spans that ran in the current or last profile capture."""
+    return _CAPTURE.seconds()
 
 
 # ------------------------------------------------- cross-thread propagation
@@ -244,9 +344,12 @@ def span_ctx(trace: Optional[Trace], name: str,
     """A live span: yields its span id, publishes itself as the thread's
     current context for the body, and records with the measured duration at
     exit.  ``attrs`` is read at exit, so the body may add outcome fields to
-    the dict it passed in.  No-op (yields ``None``) when ``trace`` is."""
+    the dict it passed in.  Yields ``None`` and records nothing when
+    ``trace`` is ``None``.  Either way the body is a :func:`span` on the
+    profiler's clock while a profile is being captured."""
     if trace is None:
-        yield None
+        with span(name):
+            yield None
         return
     sid = trace.new_span_id()
     prev = getattr(_tls, "ctx", None)
@@ -254,7 +357,8 @@ def span_ctx(trace: Optional[Trace], name: str,
     w0 = time.time()
     t0 = time.perf_counter()
     try:
-        yield sid
+        with span(name):
+            yield sid
     finally:
         _tls.ctx = prev
         trace.record(name, span_id=sid, parent_id=parent_id, start_s=w0,
@@ -263,11 +367,12 @@ def span_ctx(trace: Optional[Trace], name: str,
 
 @contextmanager
 def child_span(name: str, attrs: Optional[dict] = None):
-    """A live span under the thread's current context (no-op without one) —
-    the one-liner for instrumenting worker bodies."""
+    """A live span under the thread's current context (only a :func:`span`
+    without one) — the one-liner for instrumenting worker bodies."""
     ctx = getattr(_tls, "ctx", None)
     if ctx is None:
-        yield None
+        with span(name):
+            yield None
         return
     with span_ctx(ctx[0], name, parent_id=ctx[1], attrs=attrs) as sid:
         yield sid

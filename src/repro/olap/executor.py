@@ -55,7 +55,7 @@ from ..core.signature import Signature
 from ..core.sql_canon import CanonicalizationError, SQLCanonicalizer
 from ..core.sqlparse import SQLSyntaxError, UnsupportedQuery
 from ..core.table import ResultTable
-from ..obs.trace import adopt, child_span, current_ctx
+from ..obs.trace import adopt, child_span, current_ctx, span
 from ..resilience import faults
 from ..kernels.seg_agg.ops import (kernel_impl, seg_agg, seg_agg_batch_blocks,
                                    seg_agg_fused, seg_agg_masked)
@@ -381,24 +381,28 @@ class OlapExecutor:
                 continue
             self._count(batch_groups=1, executions=len(idxs),
                         rows_scanned=self.ds.fact.num_rows)  # one shared scan
-            levels = [self._level_plan(lv) for lv in lvls]
-            gids_np, n_groups, sparse_uniq = self._group_ids(levels)
-            gids_dev = self._device_gids(lvls, gids_np)
-            impl = self._kernel_impl()
-            rect = self._rect_index(lvls, gids_np, n_groups, impl)
-            plan = self._measure_plan(measures)
-            group_sigs = [sigs[i] for i in idxs]
-            pred_block, bounds = self._batch_predicates(group_sigs)
-            sums_dev, mms_dev = seg_agg_batch_blocks(
-                plan.sum_block, plan.minmax_block, gids_dev, pred_block,
-                bounds, n_groups, impl=impl, rect_idx=rect)
-            sums = np.asarray(sums_dev, np.float64)  # (S, G, 1+Ms)
-            mms = None if mms_dev is None else np.asarray(mms_dev, np.float64)
-            for s_i, i in enumerate(idxs):
-                out[i] = self._finalize(
-                    sigs[i], levels, plan, sums[s_i],
-                    None if mms is None else mms[s_i],
-                    gids_np, n_groups, sparse_uniq)
+            with span("olap.plan"):
+                levels = [self._level_plan(lv) for lv in lvls]
+                gids_np, n_groups, sparse_uniq = self._group_ids(levels)
+                gids_dev = self._device_gids(lvls, gids_np)
+                impl = self._kernel_impl()
+                rect = self._rect_index(lvls, gids_np, n_groups, impl)
+                plan = self._measure_plan(measures)
+                group_sigs = [sigs[i] for i in idxs]
+                pred_block, bounds = self._batch_predicates(group_sigs)
+            with span("olap.dispatch"):
+                sums_dev, mms_dev = seg_agg_batch_blocks(
+                    plan.sum_block, plan.minmax_block, gids_dev, pred_block,
+                    bounds, n_groups, impl=impl, rect_idx=rect)
+            with span("olap.wait"):
+                sums = np.asarray(sums_dev, np.float64)  # (S, G, 1+Ms)
+                mms = None if mms_dev is None else np.asarray(mms_dev, np.float64)
+            with span("olap.finalize"):
+                for s_i, i in enumerate(idxs):
+                    out[i] = self._finalize(
+                        sigs[i], levels, plan, sums[s_i],
+                        None if mms is None else mms[s_i],
+                        gids_np, n_groups, sparse_uniq)
         return out  # type: ignore[return-value]
 
     def _partition_executor(self, start: int, end: int) -> "OlapExecutor":
@@ -686,42 +690,35 @@ class OlapExecutor:
 
     # ------------------------------------------------------- fused (device)
     def _execute_fused(self, sig: Signature) -> ResultTable:
-        levels = [self._level_plan(lv) for lv in sig.levels]
-        gids_np, n_groups, sparse_uniq = self._group_ids(levels)
-        gids_dev = self._device_gids(sig.levels, gids_np)
-        impl = self._kernel_impl()
-        rect = self._rect_index(sig.levels, gids_np, n_groups, impl)
-        plan = self._measure_plan(sig.measures)
-        enc = self._predicate_plan(sig)
-        if enc is None:
-            # some predicate can't be evaluated exactly in f32: build the
-            # mask on host (exact, oracle-identical) and keep the fused
+        with span("olap.plan"):
+            levels = [self._level_plan(lv) for lv in sig.levels]
+            gids_np, n_groups, sparse_uniq = self._group_ids(levels)
+            gids_dev = self._device_gids(sig.levels, gids_np)
+            impl = self._kernel_impl()
+            rect = self._rect_index(sig.levels, gids_np, n_groups, impl)
+            plan = self._measure_plan(sig.measures)
+            enc = self._predicate_plan(sig)
+            # when some predicate can't be evaluated exactly in f32, build
+            # the mask on host (exact, oracle-identical) and keep the fused
             # single-launch device aggregation
-            mask = self._filter_mask(sig)
-            sums = np.asarray(
-                seg_agg_masked(plan.sum_block, gids_dev, mask, n_groups,
-                               "sum", impl=impl, rect_idx=rect),
-                np.float64)
-            mm = None
+            mask = self._filter_mask(sig) if enc is None else None
+        with span("olap.dispatch"):
+            if enc is None:
+                launch, args = seg_agg_masked, (gids_dev, mask, n_groups)
+            else:
+                launch, args = seg_agg_fused, (gids_dev, *enc, n_groups)
+            sums_dev = launch(plan.sum_block, *args, "sum", impl=impl,
+                              rect_idx=rect)
+            mm_dev = None
             if plan.minmax_block is not None:
-                mm = np.asarray(
-                    seg_agg_masked(plan.minmax_block, gids_dev, mask, n_groups,
-                                   "min", impl=impl, rect_idx=rect),
-                    np.float64)
-        else:
-            pred_block, bounds = enc
-            sums = np.asarray(
-                seg_agg_fused(plan.sum_block, gids_dev, pred_block, bounds,
-                              n_groups, "sum", impl=impl, rect_idx=rect),
-                np.float64)
-            mm = None
-            if plan.minmax_block is not None:
-                mm = np.asarray(
-                    seg_agg_fused(plan.minmax_block, gids_dev, pred_block, bounds,
-                                  n_groups, "min", impl=impl, rect_idx=rect),
-                    np.float64)
-        return self._finalize(sig, levels, plan, sums, mm, gids_np, n_groups,
-                              sparse_uniq)
+                mm_dev = launch(plan.minmax_block, *args, "min", impl=impl,
+                                rect_idx=rect)
+        with span("olap.wait"):
+            sums = np.asarray(sums_dev, np.float64)
+            mm = None if mm_dev is None else np.asarray(mm_dev, np.float64)
+        with span("olap.finalize"):
+            return self._finalize(sig, levels, plan, sums, mm, gids_np,
+                                  n_groups, sparse_uniq)
 
     def _finalize(self, sig, levels, plan, sums, mm, gids_np, n_groups,
                   sparse_uniq) -> ResultTable:

@@ -332,6 +332,8 @@ class TenantStats:
     shed: int = 0  # guarded-by: self._lock
     failures: int = 0  # guarded-by: self._lock
     store_errors: int = 0  # guarded-by: self._lock
+    # leaders re-run alone after their shared batch scan failed
+    isolated_retries: int = 0  # guarded-by: self._lock
     stage_timings: dict = dataclasses.field(  # guarded-by: self._lock
         default_factory=dict, repr=False, compare=False)
     _lock: threading.Lock = dataclasses.field(

@@ -51,6 +51,7 @@ boundaries so every one of those promises is testable deterministically.
 from __future__ import annotations
 
 import dataclasses
+import itertools
 import time
 from concurrent.futures import ThreadPoolExecutor
 from typing import Optional, TYPE_CHECKING
@@ -61,7 +62,7 @@ from ..core.safety import gate_nl, verify_hit_time_window
 from ..core.signature import Signature
 from ..core.sql_canon import CanonicalizationError
 from ..core.sqlparse import SQLSyntaxError, UnsupportedQuery
-from ..obs.trace import Trace, adopt, span_ctx
+from ..obs.trace import Trace, adopt, profiling, span, span_ctx
 from ..resilience import faults
 from ..resilience.errors import FailureInfo, classify
 from ..resilience.primitives import Deadline, backoff_delays
@@ -71,6 +72,10 @@ if TYPE_CHECKING:  # pragma: no cover
     from .service import Tenant
 
 STAGES = ("canonicalize", "validate", "gate", "lookup", "plan", "execute", "store")
+
+# one id per run_pipeline call, carried by the batch's spans on the
+# profiler's clock (next() on itertools.count is GIL-atomic)
+_submit_ids = itertools.count(1)
 
 
 @dataclasses.dataclass
@@ -105,21 +110,29 @@ class RequestState:
     provenance: list = dataclasses.field(default_factory=list)
     timings: dict = dataclasses.field(default_factory=dict)
     # observability: set when this request was head-sampled.  Stage spans
-    # are emitted at finalize time from ``timings``/``provenance`` (no
-    # second clock read per stage); ``stage_attrs`` collects extra span
-    # attributes stages want on their finalize-time span (adoption links,
-    # resilience outcomes)
+    # are emitted at finalize time from ``starts``/``timings``/
+    # ``provenance`` (no second clock read per stage); ``stage_attrs``
+    # collects extra span attributes stages want on their finalize-time span
+    # (adoption links, resilience outcomes)
     trace: Optional[Trace] = None
     trace_wall0: float = 0.0  # wall clock at trace start (span start_s base)
     trace_t0: float = 0.0  # perf_counter at trace start (root span duration)
+    # sampled requests only: perf_counter at each stage's first start
+    starts: dict = dataclasses.field(default_factory=dict)
     stage_attrs: dict = dataclasses.field(default_factory=dict)
 
     @property
     def pending(self) -> bool:
         return self.status is None
 
-    def add_ms(self, stage: str, ms: float) -> None:
+    def add_ms(self, stage: str, ms: float, t0: Optional[float] = None) -> None:
+        """Add ``ms`` to the stage's timing; ``t0`` is the ``perf_counter``
+        reading the stage's clock started from (kept for sampled requests;
+        a timing without one started ``ms`` ago)."""
         self.timings[stage] = self.timings.get(stage, 0.0) + ms
+        if self.trace is not None and stage not in self.starts:
+            self.starts[stage] = (time.perf_counter() - ms / 1e3
+                                  if t0 is None else t0)
 
     def bypass(self, reason: str, exec_mode: Optional[str] = None) -> None:
         self.status = "bypass"
@@ -155,15 +168,17 @@ def run_pipeline(tenant: "Tenant", requests: list[QueryRequest]) -> list[QueryRe
                 s.trace_wall0 = time.time()
                 s.trace_t0 = time.perf_counter()
     tenant.stats.bump(requests=len(states), batches=1)
+    # spans on the profiler's clock: one check per batch, and spans only
+    # while a profile is being captured
+    submit = next(_submit_ids) if profiling() else None
     try:
-        for name, stage in (("canonicalize", _stage_canonicalize),
-                            ("validate", _stage_validate),
-                            ("gate", _stage_gate),
-                            ("lookup", _stage_lookup),
-                            ("execute", _stage_plan_and_execute),
-                            ("store", _stage_store)):
+        for name, stage in _BATCH_STAGES:
             try:
-                stage(tenant, states)
+                if submit is None:
+                    stage(tenant, states)
+                else:
+                    with span(f"service.{name}", submit=submit):
+                        stage(tenant, states)
             except Exception as e:  # noqa: BLE001 — containment boundary
                 # a stage-level crash must not escape as a raw exception:
                 # every still-pending request resolves to a typed error, and
@@ -183,7 +198,10 @@ def run_pipeline(tenant: "Tenant", requests: list[QueryRequest]) -> list[QueryRe
                 if s.flight is not None and s.flight_leader and not s.flight.done:
                     fail(s.flight,
                          RuntimeError("pipeline aborted before flight completion"))
-    return [_finalize(tenant, s) for s in states]
+    if submit is None:
+        return [_finalize(tenant, s) for s in states]
+    with span("service.finalize", submit=submit):
+        return [_finalize(tenant, s) for s in states]
 
 
 # ------------------------------------------------------------ failure paths
@@ -264,12 +282,12 @@ def _stage_canonicalize(tenant: "Tenant", states: list[RequestState]) -> None:
                 if s.req.scope is not None:
                     s.sig = s.sig.replace(scope=s.req.scope)
         except (UnsupportedQuery, SQLSyntaxError, CanonicalizationError, KeyError) as e:
-            s.add_ms("canonicalize", (time.perf_counter() - t0) * 1e3)
+            s.add_ms("canonicalize", (time.perf_counter() - t0) * 1e3, t0)
             # raw-SQL bypasses still run on the backend; metric/signature
             # failures have nothing safe to execute
             s.bypass(str(e), "raw" if s.origin == "sql" else None)
             continue
-        s.add_ms("canonicalize", (time.perf_counter() - t0) * 1e3)
+        s.add_ms("canonicalize", (time.perf_counter() - t0) * 1e3, t0)
         s.provenance.append(f"canonicalize:{s.origin}")
 
 
@@ -322,7 +340,7 @@ def _canonicalize_nl(tenant: "Tenant", states: list[RequestState]) -> None:
             if pol.enabled:
                 breaker.record_failure()
             for s in group:
-                s.add_ms("canonicalize", ms)
+                s.add_ms("canonicalize", ms, t0)
                 _conclude_failure(
                     tenant, s, "canonicalize", classify(e),
                     f"{type(e).__name__}: {e}",
@@ -341,7 +359,7 @@ def _canonicalize_nl(tenant: "Tenant", states: list[RequestState]) -> None:
                     error="injected fault: canonicalizer returned garbage")
             elif faults.should_fire("canonicalize.lowconf"):
                 res = dataclasses.replace(res, confidence=0.01)
-            s.add_ms("canonicalize", ms)
+            s.add_ms("canonicalize", ms, t0)
             s.nl_res = res
             s.confidence = res.confidence
             sig = res.signature
@@ -364,7 +382,7 @@ def _stage_validate(tenant: "Tenant", states: list[RequestState]) -> None:
             continue
         t0 = time.perf_counter()
         v = tenant.validator.validate(s.sig)
-        s.add_ms("validate", (time.perf_counter() - t0) * 1e3)
+        s.add_ms("validate", (time.perf_counter() - t0) * 1e3, t0)
         if v:
             s.provenance.append("validate:ok")
             continue
@@ -388,7 +406,7 @@ def _stage_gate(tenant: "Tenant", states: list[RequestState]) -> None:
         if s.origin == "nl":
             t0 = time.perf_counter()
             gate = gate_nl(tenant.policy, s.req.nl, s.nl_res, s.req.now)
-            s.add_ms("gate", (time.perf_counter() - t0) * 1e3)
+            s.add_ms("gate", (time.perf_counter() - t0) * 1e3, t0)
             if not gate:
                 tenant.stats.bump(nl_gated=1)
                 # the signature is schema-valid: the bypass still executes it,
@@ -428,7 +446,7 @@ def _stage_lookup(tenant: "Tenant", states: list[RequestState]) -> None:
             (s.sig, "nl" if s.origin == "nl" else "sql") for s in todo])
         ms = (time.perf_counter() - t0) * 1e3 / len(todo)
         for s, (lr, flight, leader) in zip(todo, triples):
-            s.add_ms("lookup", ms)
+            s.add_ms("lookup", ms, t0)
             _apply_lookup(tenant, s, lr)
             if s.pending:
                 s.flight, s.flight_leader = flight, leader
@@ -443,7 +461,7 @@ def _stage_lookup(tenant: "Tenant", states: list[RequestState]) -> None:
         t0 = time.perf_counter()
         lr: LookupResult = tenant.cache.lookup(
             s.sig, request_origin="nl" if s.origin == "nl" else "sql")
-        s.add_ms("lookup", (time.perf_counter() - t0) * 1e3)
+        s.add_ms("lookup", (time.perf_counter() - t0) * 1e3, t0)
         _apply_lookup(tenant, s, lr)
 
 
@@ -497,7 +515,7 @@ def _stage_plan_and_execute(tenant: "Tenant", states: list[RequestState]) -> Non
         # second SHA-256 — the one-hash-per-request invariant is
         # regression-tested via signature.key_hash_computations()
         misses.setdefault(s.sig.key(), []).append(s)
-        s.add_ms("plan", (time.perf_counter() - t0) * 1e3)
+        s.add_ms("plan", (time.perf_counter() - t0) * 1e3, t0)
 
     leaders = [group[0] for group in misses.values()]
     for group in misses.values():
@@ -599,7 +617,7 @@ def _stage_plan_and_execute(tenant: "Tenant", states: list[RequestState]) -> Non
                 else:
                     s.table = tenant.backend.execute(s.sig)
         except Exception as e:  # noqa: BLE001 — containment boundary
-            s.add_ms("execute", (time.perf_counter() - t0) * 1e3)
+            s.add_ms("execute", (time.perf_counter() - t0) * 1e3, t0)
             s.status = "error"
             s.table = None
             s.error = FailureInfo(stage="execute", kind=classify(e),
@@ -607,7 +625,7 @@ def _stage_plan_and_execute(tenant: "Tenant", states: list[RequestState]) -> Non
             s.provenance.append(f"failure:{s.error.brief()}")
             tenant.stats.bump(failures=1)
             continue
-        s.add_ms("execute", (time.perf_counter() - t0) * 1e3)
+        s.add_ms("execute", (time.perf_counter() - t0) * 1e3, t0)
         tenant.stats.bump(backend_executions=1)
         s.provenance.append(f"execute:bypass_{s.bypass_exec}")
 
@@ -628,7 +646,7 @@ def _execute_leader_group(tenant: "Tenant", group: list[RequestState]) -> None:
             s.batched = True
             # the scan is shared: each request is attributed the full batch
             # wall time under 'execute' (not a per-request cost)
-            s.add_ms("execute", batch_ms)
+            s.add_ms("execute", batch_ms, t0)
             s.provenance.append("execute:batched")
             if partitioned:
                 s.provenance.append("execute:partitioned")
@@ -637,7 +655,7 @@ def _execute_leader_group(tenant: "Tenant", group: list[RequestState]) -> None:
         t0 = time.perf_counter()
         with tenant.gate.read:
             s.table = tenant.backend.execute(s.sig)
-        s.add_ms("execute", (time.perf_counter() - t0) * 1e3)
+        s.add_ms("execute", (time.perf_counter() - t0) * 1e3, t0)
         s.provenance.append("execute:single")
         if partitioned:
             s.provenance.append("execute:partitioned")
@@ -737,6 +755,7 @@ def _execute_group_guarded(tenant: "Tenant",
         # isolate and re-run each leader alone so one bad intent cannot
         # take down its co-batched innocents
         ok = True
+        tenant.stats.bump(isolated_retries=len(group))
         for s in group:
             s.provenance.append("execute:isolated_retry")
             ok = _execute_group_guarded(tenant, [s]) and ok
@@ -778,7 +797,7 @@ def _resolve_follower(tenant: "Tenant", s: RequestState) -> None:
     timeout = getattr(tenant.cache, "flight_timeout", 30.0)
     t0 = time.perf_counter()
     ok = s.flight.wait(timeout)
-    s.add_ms("plan", (time.perf_counter() - t0) * 1e3)
+    s.add_ms("plan", (time.perf_counter() - t0) * 1e3, t0)
     s.deduped = True
     lctx = getattr(s.flight, "obs_ctx", None)
     if ok and lctx is not None:
@@ -825,11 +844,11 @@ def _store_state(tenant: "Tenant", s: RequestState) -> None:
                              cost_ms=s.timings.get("execute", 0.0))
     except Exception:  # noqa: BLE001 — a failed store must not fail the
         # request: the table is already in hand, the cache just stays cold
-        s.add_ms("store", (time.perf_counter() - t0) * 1e3)
+        s.add_ms("store", (time.perf_counter() - t0) * 1e3, t0)
         s.provenance.append("store:error")
         tenant.stats.bump(store_errors=1)
         return
-    s.add_ms("store", (time.perf_counter() - t0) * 1e3)
+    s.add_ms("store", (time.perf_counter() - t0) * 1e3, t0)
     s.stored = True
     tenant.stats.bump(stores=1)
     s.provenance.append("store")
@@ -849,6 +868,15 @@ def _stage_store(tenant: "Tenant", states: list[RequestState]) -> None:
         _store_state(tenant, s)
 
 
+# the batch-level stages in order ('execute' plans and executes)
+_BATCH_STAGES = (("canonicalize", _stage_canonicalize),
+                 ("validate", _stage_validate),
+                 ("gate", _stage_gate),
+                 ("lookup", _stage_lookup),
+                 ("execute", _stage_plan_and_execute),
+                 ("store", _stage_store))
+
+
 # ----------------------------------------------------------------- finalize
 
 
@@ -858,7 +886,11 @@ def _emit_trace(s: RequestState) -> None:
     ``timings`` and provenance-derived stage names (a failed execute that
     never recorded a timing still proves its passage via provenance, and
     the error's own stage is always covered), so trace completeness holds
-    by construction — including under injected chaos."""
+    by construction — including under injected chaos.
+
+    A stage's span starts at the stage's first start and lasts its summed
+    timing, so it ends no later than the stage did.  A stage passed without
+    a timing is a zero-length span where the previous stage's span ended."""
     tr = s.trace
     by_stage: dict[str, list[str]] = {}
     events: list[str] = []
@@ -872,14 +904,13 @@ def _emit_trace(s: RequestState) -> None:
     stages = set(s.timings) | set(by_stage)
     if s.error is not None and s.error.stage in STAGES:
         stages.add(s.error.stage)
-    # stages are laid out sequentially from the request's start: per-stage
-    # starts were never recorded (tracing adds no clock reads to stages),
-    # durations are the pipeline's own perf_counter timings
-    cursor = s.trace_wall0
+    prev_end = s.trace_wall0
     for stage in STAGES:
         if stage not in stages:
             continue
         dur = s.timings.get(stage, 0.0)
+        t0 = s.starts.get(stage)
+        start = prev_end if t0 is None else s.trace_wall0 + (t0 - s.trace_t0)
         attrs: dict = {}
         if stage in by_stage:
             attrs["outcomes"] = by_stage[stage]
@@ -898,9 +929,9 @@ def _emit_trace(s: RequestState) -> None:
             for tok in events:
                 if tok.startswith("retry:"):
                     attrs.setdefault("retries", int(tok.split(":", 1)[1]))
-        tr.record(stage, parent_id=tr.root_id, start_s=cursor, dur_ms=dur,
+        tr.record(stage, parent_id=tr.root_id, start_s=start, dur_ms=dur,
                   attrs=attrs)
-        cursor += dur / 1e3
+        prev_end = start + dur / 1e3
     root_attrs: dict = {
         "status": s.status or "bypass",
         "origin": s.origin,

@@ -32,6 +32,7 @@ from ..core.schema import StarSchema
 from ..core.sql_canon import SQLCanonicalizer
 from ..core.validator import SignatureValidator
 from ..obs import ObsConfig, ObsPlane
+from ..obs.compiles import COMPILES
 from ..resilience import faults
 from ..resilience.policy import ResiliencePolicy, TenantResilience
 from .api import (DEFAULT_TENANT, Backend, QueryRequest, QueryResult,
@@ -101,6 +102,8 @@ class CacheService:
         if isinstance(obs, ObsConfig):
             obs = ObsPlane(obs)
         self.obs: ObsPlane = obs if obs is not None else ObsPlane()
+        # the process's XLA compilations, mirrored by metrics()
+        COMPILES.install()
 
     # ----------------------------------------------------------- tenants
     def register_tenant(
@@ -515,6 +518,12 @@ class CacheService:
             arr.set_total(n, point=point)
         for point, n in fc["fired"].items():
             fired.set_total(n, point=point)
+        compiles, compile_s = COMPILES.snapshot()
+        reg.counter("xla_compiles_total",
+                    "XLA compilations in this process (programs compiled or "
+                    "loaded from the persistent cache)").set_total(compiles)
+        reg.counter("xla_compile_seconds_total",
+                    "seconds spent in those compilations").set_total(compile_s)
         tr = self.obs.tracer.stats()
         reg.counter("traces_seen_total",
                     "requests considered for sampling").set_total(tr["seen"])

@@ -495,3 +495,108 @@ class TestObsCli:
         ]
         ok.write_text("\n".join(json.dumps(e) for e in evts))
         assert obs_main(["false-hits", str(ok)]) == 0
+
+
+# ------------------------------------------------ spans on the profiler clock
+
+
+class TestProfilerSpans:
+    def test_annotations_only_while_a_profile_is_captured(self, monkeypatch,
+                                                         tmp_path):
+        import jax
+
+        from repro.obs import trace as T
+
+        T.profiling()  # binds TraceAnnotation
+        made = []
+        real = T._annotation
+
+        def counting(name, **ids):
+            made.append((name, ids))
+            return real(name, **ids)
+
+        monkeypatch.setattr(T, "_annotation", counting)
+        with T.span("olap.plan", submit=1):
+            pass
+        with span_ctx(None, "execute.backend"):
+            pass
+        with child_span("execute.partition"):
+            pass
+        assert not T.profiling() and made == []
+        with jax.profiler.trace(str(tmp_path / "a")):
+            with T.span("service.execute", submit=5):
+                with T.span("olap.plan"):
+                    pass
+            with span_ctx(None, "execute.backend"):
+                pass
+        assert [n for n, _ in made] == ["repro.service.execute",
+                                        "repro.olap.plan",
+                                        "repro.execute.backend"]
+        # a nested span on the same thread carries the submit id
+        assert made[1][1] == {"submit": 5}
+        assert set(T.profile_span_seconds()) == {
+            "service.execute", "olap.plan", "execute.backend"}
+        # the next capture's seconds do not mix with this one's
+        with T.span("olap.wait"):
+            pass
+        with jax.profiler.trace(str(tmp_path / "b")):
+            with T.span("olap.wait"):
+                time.sleep(0.01)
+        secs = T.profile_span_seconds()
+        assert set(secs) == {"olap.wait"} and secs["olap.wait"] >= 0.01
+
+    def test_compile_counter_counts_fresh_jit_not_cached_call(self, ssb_small):
+        import jax
+        import jax.numpy as jnp
+
+        from repro.obs.compiles import COMPILES
+
+        svc = mk_service(ssb_small)  # installs the listener
+        COMPILES.install()  # a second install registers nothing more
+        f = jax.jit(lambda x: x * 3.0 + 1.0)
+        x = jnp.arange(13.0)
+        n0, s0 = COMPILES.snapshot()
+        f(x).block_until_ready()
+        n1, s1 = COMPILES.snapshot()
+        f(x).block_until_ready()
+        n2, _ = COMPILES.snapshot()
+        assert n1 == n0 + 1 and s1 > s0
+        assert n2 == n1
+        text = svc.metrics()
+        assert f"repro_xla_compiles_total {n2}\n" in text
+        assert "repro_xla_compile_seconds_total" in text
+
+    def test_sampled_stage_spans_have_real_starts(self, ssb_small):
+        """Stage spans start where their stage did: inside the root span,
+        never overlapping, batch-level stages in batch order (every
+        request's canonicalize ends before any request's validate starts),
+        and a shared scan's execute starting at one instant for all of its
+        requests."""
+        svc = mk_service(ssb_small, ObsConfig.full(sample_rate=1.0))
+        svc.submit(QueryRequest(sql=sql_region(), tenant="t"))
+        res = svc.submit_batch(
+            [QueryRequest(sql=sql_region(where=f"d_year = {1992 + i}"),
+                          tenant="t") for i in range(3)]
+            + [QueryRequest(sql=sql_region(), tenant="t")])
+        assert [r.status for r in res] == ["miss"] * 3 + ["hit_exact"]
+        eps = 1e-6  # wall-clock seconds as doubles
+        by_stage: dict = {}
+        for r in res:
+            spans = svc.obs.tracer.spans(r.trace_id)
+            root = next(s for s in spans if s["name"] == "request")
+            r0, r1 = root["start_s"], root["start_s"] + root["dur_ms"] / 1e3
+            stages = sorted(
+                ((s["start_s"], s["start_s"] + s["dur_ms"] / 1e3, s["name"])
+                 for s in spans if s["name"] in PIPELINE_STAGES))
+            assert {n for _, _, n in stages} >= {"canonicalize", "lookup"}
+            for a, b, _ in stages:
+                assert r0 - eps <= a <= b <= r1 + eps
+            for (_, b, _), (a, _, _) in zip(stages, stages[1:]):
+                assert b <= a + eps
+            for a, b, n in stages:
+                by_stage.setdefault(n, []).append((a, b))
+        assert max(b for _, b in by_stage["canonicalize"]) <= \
+            min(a for a, _ in by_stage["validate"]) + eps
+        assert len(by_stage["execute"]) == 3
+        starts = [a for a, _ in by_stage["execute"]]
+        assert max(starts) - min(starts) <= 2 * eps

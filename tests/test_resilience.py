@@ -256,6 +256,24 @@ class TestPipelineContainment:
         assert t.resilience.backend.state == "closed"
         assert t.resilience.backend.snapshot()["closes"] == 1
 
+    def test_isolated_retries_counted(self, ssb_small):
+        class SharedScanFails(OlapExecutor):
+            def execute_batch(self, sigs, partition=None):
+                raise RuntimeError("shared scan failed")
+
+        svc = mk_service(ssb_small,
+                         policy=ResiliencePolicy(execute_attempts=1),
+                         backend=SharedScanFails(ssb_small.dataset,
+                                                 impl="numpy"))
+        res = svc.submit_batch([
+            QueryRequest(sql=sql_region(where=f"d_year = {1992 + i}"),
+                         tenant="t") for i in range(3)])
+        assert all(r.status == "miss" for r in res)
+        assert all("execute:isolated_retry" in r.provenance for r in res)
+        assert svc.tenant("t").stats.isolated_retries == 3
+        assert 'repro_service_isolated_retries_total{tenant="t"} 3' \
+            in svc.metrics()
+
     def test_partial_partition_failure_fails_whole_batch_result(self, ssb_small):
         be = OlapExecutor(ssb_small.dataset, impl="numpy", partitions=2)
         svc = mk_service(ssb_small, backend=be,

@@ -9,6 +9,7 @@ program and bounds its temp bytes below what the previous (N, M) layout
 needed (9.2 GB for one 3-measure SUM at 6M rows).
 """
 import os
+import re
 
 import jax
 import jax.numpy as jnp
@@ -40,11 +41,16 @@ def one_chip():
     jax.config.update("jax_enable_compilation_cache", was)
 
 
-def _compile(fn, shapes, sharding):
-    """Compile ``fn`` for the described chip; return its temp bytes."""
+def _compile(fn, shapes, sharding, kernel=None):
+    """Compile ``fn`` for the described chip; return its temp bytes.  With
+    ``kernel``, the Pallas call must carry that name in the compiled
+    program, where a profiler trace reads it."""
     args = [jax.ShapeDtypeStruct(s, d, sharding=sharding) for s, d in shapes]
     compiled = jax.jit(fn).lower(*args).compile()
-    assert "tpu_custom_call" in compiled.as_text()
+    text = compiled.as_text()
+    assert "tpu_custom_call" in text
+    if kernel is not None:
+        assert re.search(rf"%{kernel}(\.\d+)? = .*tpu_custom_call", text)
     return compiled.memory_analysis().temp_size_in_bytes
 
 
@@ -56,7 +62,8 @@ def test_seg_agg_pallas_compiles(one_chip, op):
     from repro.kernels.seg_agg.kernel import seg_agg_pallas
 
     temp = _compile(lambda v, i, m: seg_agg_pallas(v, i, m, G, op),
-                    [((N, M), F32), ((N,), I32), ((N,), F32)], one_chip)
+                    [((N, M), F32), ((N,), I32), ((N,), F32)], one_chip,
+                    kernel=f"seg_agg_{op}")
     assert temp < GB // 2
 
 
@@ -66,7 +73,7 @@ def test_seg_agg_fused_pallas_compiles(one_chip, op):
 
     temp = _compile(lambda v, i, p, b: seg_agg_fused_pallas(v, i, p, b, G, op),
                     [((N, M), F32), ((N,), I32), ((N, P), F32), ((P, 2 * K), F32)],
-                    one_chip)
+                    one_chip, kernel=f"seg_agg_fused_{op}")
     assert temp < GB // 2
 
 
@@ -79,7 +86,7 @@ def test_seg_agg_batch_blocks_pallas_compiles(one_chip, op):
 
     temp = _compile(lambda v, i, p, b: _batch_jit(v, i, p, b, G, op, "pallas"),
                     [((N, 1 + M), F32), ((N,), I32), ((N, P), F32),
-                     ((S, P, K, 2), F32)], one_chip)
+                     ((S, P, K, 2), F32)], one_chip, kernel=f"seg_agg_{op}")
     assert temp < 3 * GB
 
 
