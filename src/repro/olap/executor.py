@@ -42,6 +42,7 @@ from __future__ import annotations
 
 import collections
 import dataclasses
+import math
 import threading as _threading
 from concurrent.futures import ThreadPoolExecutor
 from typing import Callable, Optional, Sequence
@@ -62,7 +63,7 @@ from ..kernels.seg_agg.ops import (kernel_impl, seg_agg, seg_agg_batch_blocks,
 from . import scan_plane
 from .columnar import Dataset, date_to_days
 
-MAX_DENSE_GROUPS = 1 << 20  # dense group-space cap for the segment-reduce path
+MAX_DENSE_GROUPS = 1 << 20  # above this, observed groups are found by sort, not bincount
 
 DEFAULT_MEMO_CAP = 64  # per-executor LRU bound on plan/index memo dicts
 
@@ -204,6 +205,11 @@ class OlapExecutor:
         self.partition_fallbacks = 0  # guarded-by: self._count_lock
         # chunk scans beyond the first per partition
         self.streaming_chunks = 0  # guarded-by: self._count_lock
+        # group spaces of the device scans (shared and fused single): the
+        # dense product of level cardinalities, and the compacted count the
+        # kernel reduces
+        self.dense_groups = 0  # guarded-by: self._count_lock
+        self.kernel_groups = 0  # guarded-by: self._count_lock
         # the cluster miss planner runs shard groups on concurrent threads;
         # bare '+=' on shared counters would drop increments
         self._count_lock = make_lock("OlapExecutor._count_lock")
@@ -220,12 +226,19 @@ class OlapExecutor:
         self._devices = _UNSET
 
     def _count(self, executions: int = 0, rows_scanned: int = 0,
-               batch_calls: int = 0, batch_groups: int = 0) -> None:
+               batch_calls: int = 0, batch_groups: int = 0,
+               dense_groups: int = 0, kernel_groups: int = 0) -> None:
         with self._count_lock:
             self.executions += executions
             self.rows_scanned += rows_scanned
             self.batch_calls += batch_calls
             self.batch_groups += batch_groups
+            self.dense_groups += dense_groups
+            self.kernel_groups += kernel_groups
+
+    def _count_groups(self, levels: list[_LevelPlan], n_groups: int) -> None:
+        self._count(dense_groups=math.prod(lp.card for lp in levels),
+                    kernel_groups=n_groups)
 
     # ------------------------------------------------------- memo LRU bounds
     def _dev_drop(self, *keys) -> None:
@@ -272,6 +285,8 @@ class OlapExecutor:
                 "partitioned_scans": self.partitioned_scans,
                 "partition_fallbacks": self.partition_fallbacks,
                 "streaming_chunks": self.streaming_chunks,
+                "dense_groups": self.dense_groups,
+                "kernel_groups": self.kernel_groups,
                 "memo_sizes": self.memo_sizes(),
                 "per_partition": [dict(p) for p in self._pstats],
             }
@@ -351,7 +366,9 @@ class OlapExecutor:
             self._count(executions=sub.executions,
                         rows_scanned=sub.rows_scanned,
                         batch_calls=sub.batch_calls,
-                        batch_groups=sub.batch_groups)
+                        batch_groups=sub.batch_groups,
+                        dense_groups=sub.dense_groups,
+                        kernel_groups=sub.kernel_groups)
             return out
         if self._scan_active():
             return self._execute_batch_partitioned(sigs)
@@ -384,6 +401,7 @@ class OlapExecutor:
             with span("olap.plan"):
                 levels = [self._level_plan(lv) for lv in lvls]
                 gids_np, n_groups, sparse_uniq = self._group_ids(levels)
+                self._count_groups(levels, n_groups)
                 gids_dev = self._device_gids(lvls, gids_np)
                 impl = self._kernel_impl()
                 rect = self._rect_index(lvls, gids_np, n_groups, impl)
@@ -563,15 +581,15 @@ class OlapExecutor:
                     args=(next_sub, psigs, dev, staged_errors), daemon=True)
                 stager.start()
             with sub._scan_mutex:
-                before = (sub.executions, sub.rows_scanned, sub.batch_groups)
+                before = sub.stats()
                 tables = sub.execute_batch(psigs)
-                delta = (sub.executions - before[0],
-                         sub.rows_scanned - before[1],
-                         sub.batch_groups - before[2])
+                after = sub.stats()
+            delta = {c: after[c] - before[c] for c in (
+                "executions", "rows_scanned", "batch_groups",
+                "dense_groups", "kernel_groups")}
             for i, t in enumerate(tables):
                 per_sig[i].append(t)
-            self._note_partition(p, rows=delta[1], executions=delta[0],
-                                 groups=delta[2], chunk_no=k)
+            self._note_partition(p, delta, chunk_no=k)
             if streaming:
                 self._release_chunk(sub)
             if stager is not None:
@@ -628,17 +646,21 @@ class OlapExecutor:
             dev._store.clear()
         sub.ds._device = None
 
-    def _note_partition(self, p: int, rows: int, executions: int,
-                        groups: int, chunk_no: int) -> None:
+    def _note_partition(self, p: int, delta: dict, chunk_no: int) -> None:
+        """Fold one chunk scan's counter deltas into this executor: rows and
+        group spaces into the totals, rows/executions/shared scans into the
+        partition's entry."""
         with self._count_lock:
-            self.rows_scanned += rows
+            self.rows_scanned += delta["rows_scanned"]
+            self.dense_groups += delta["dense_groups"]
+            self.kernel_groups += delta["kernel_groups"]
             if chunk_no > 0:
                 self.streaming_chunks += 1
             if p < len(self._pstats):
                 st = self._pstats[p]
-                st["rows_scanned"] += rows
-                st["executions"] += executions
-                st["batch_groups"] += groups
+                st["rows_scanned"] += delta["rows_scanned"]
+                st["executions"] += delta["executions"]
+                st["batch_groups"] += delta["batch_groups"]
                 st["chunks"] += 1
 
     def _prestage(self, sub: "OlapExecutor", psigs, dev,
@@ -693,6 +715,7 @@ class OlapExecutor:
         with span("olap.plan"):
             levels = [self._level_plan(lv) for lv in sig.levels]
             gids_np, n_groups, sparse_uniq = self._group_ids(levels)
+            self._count_groups(levels, n_groups)
             gids_dev = self._device_gids(sig.levels, gids_np)
             impl = self._kernel_impl()
             rect = self._rect_index(sig.levels, gids_np, n_groups, impl)
@@ -1156,14 +1179,23 @@ class OlapExecutor:
         return lp
 
     def _group_ids(self, levels: list[_LevelPlan]) -> tuple[np.ndarray, int, Optional[np.ndarray]]:
-        """Dense (or compacted-sparse) group ids for a level combination.
+        """Group ids for a level combination, compacted to the groups the
+        fact rows hold.
 
-        Returns ``(gids, n_groups, sparse_uniq)`` — ``sparse_uniq`` is the
-        observed-group compaction table (None on the dense path) and is
-        threaded through to ``_decode_groups`` by the caller instead of
-        living in mutable instance state (stale/racy across calls).
-        Memoized per level combination: the mapping depends only on the
-        dataset, not on the query's filters.
+        The dense id of a row is its level codes in mixed radix over the
+        dimension cardinalities; a dimension usually has values no fact row
+        references (a 200-year date_dim under 5 years of sales), and the
+        kernels' work grows with the group count.  So the ids are renumbered
+        0..n_groups-1 over the observed dense ids, in ascending order: a
+        presence bincount (O(N)) up to ``MAX_DENSE_GROUPS``, a sort above.
+
+        Returns ``(gids, n_groups, sparse_uniq)`` — ``sparse_uniq`` maps a
+        compacted id back to its dense id (None when every dense group is
+        observed and the ids are left as they are) and is threaded through
+        to ``_decode_groups`` by the caller instead of living in mutable
+        instance state (stale/racy across calls).  Memoized per level
+        combination: the mapping depends only on the dataset, not on the
+        query's filters; an append clears it (``_sync``).
         """
         n = self.ds.fact.num_rows
         if not levels:
@@ -1178,11 +1210,18 @@ class OlapExecutor:
             gids = gids * lp.card + lp.codes
             g *= lp.card
         if g > MAX_DENSE_GROUPS:
-            # compact the observed group space (rare for dashboard queries)
+            # a (g,) presence table would not fit: find the observed groups
+            # by sort
             uniq, gids = np.unique(gids, return_inverse=True)
             result = (gids.astype(np.int32), len(uniq), uniq)
         else:
-            result = (gids.astype(np.int32), g, None)
+            present = np.bincount(gids, minlength=g) > 0
+            uniq = np.flatnonzero(present)
+            if 0 < len(uniq) < g:
+                lut = (np.cumsum(present) - 1).astype(np.int32)
+                result = (lut[gids], len(uniq), uniq)
+            else:  # every dense group observed (or no rows): ids as they are
+                result = (gids.astype(np.int32), g, None)
         self._gids_cache[cache_key] = result
         return result
 

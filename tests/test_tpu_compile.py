@@ -90,6 +90,22 @@ def test_seg_agg_batch_blocks_pallas_compiles(one_chip, op):
     assert temp < 3 * GB
 
 
+def test_refresh_batch_compacted_groups_compiles(one_chip):
+    """The TPC-DS SF10 refresh dashboard's shared scan (28,800,991 rows, 12
+    tiles, d_year x s_state, two columns per block) at its 60 observed
+    groups, one 128-group tile: it compiles, in no more temp than the 2,010
+    dense groups of the full date_dim need."""
+    from repro.kernels.seg_agg.ops import _batch_jit
+
+    n, s, m, p, k = 28_800_991, 12, 2, 3, 1
+    shapes = [((n, m), F32), ((n,), I32), ((n, p), F32), ((s, p, k, 2), F32)]
+    temp = {g: _compile(lambda v, i, pc, b, g=g: _batch_jit(v, i, pc, b, g, "min",
+                                                             "pallas"),
+                        shapes, one_chip, kernel="seg_agg_min")
+            for g in (60, 2010)}
+    assert temp[60] <= temp[2010]
+
+
 def test_flash_attention_compiles(one_chip):
     """canonicalizer-100m prefill: B=8, S=256, 12 query / 4 KV heads of 64."""
     from repro.configs.registry import get
