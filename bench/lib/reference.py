@@ -15,6 +15,7 @@ aggregated (in float64): the control that a correct comparison has to fail.
 from __future__ import annotations
 
 import dataclasses
+import re
 import threading
 from typing import Optional
 
@@ -49,6 +50,87 @@ def render_expr(expr) -> str:
     if isinstance(expr, str):
         return expr.split(".", 1)[1]
     return f"{render_expr(expr[1])} {expr[0]} {render_expr(expr[2])}"
+
+
+_TOKEN = re.compile(r"\s*([A-Za-z_][A-Za-z_0-9]*\.[A-Za-z_][A-Za-z_0-9]*|[-+*/()])")
+
+
+def parse_expr(text: str):
+    """The expression tree of a signature's measure text, e.g.
+    ``(lineorder.lo_discount*lineorder.lo_extendedprice)`` ->
+    ``["*", "lineorder.lo_discount", "lineorder.lo_extendedprice"]``; None
+    where the text holds anything but qualified columns, + - * / and
+    parentheses."""
+    if text.strip() == "*":
+        return "*"
+    toks, pos = [], 0
+    while pos < len(text.rstrip()):
+        m = _TOKEN.match(text, pos)
+        if m is None:
+            return None
+        toks.append(m.group(1))
+        pos = m.end()
+    at = [0]
+
+    def peek():
+        return toks[at[0]] if at[0] < len(toks) else None
+
+    def take():
+        at[0] += 1
+        return toks[at[0] - 1]
+
+    def atom():
+        t = peek()
+        if t == "(":
+            take()
+            e = expr()
+            if e is None or peek() != ")":
+                return None
+            take()
+            return e
+        if t is None or t in "+-*/()":
+            return None
+        return take()
+
+    def chain(sub, ops):
+        e = sub()
+        while e is not None and peek() in ops:
+            op = take()
+            r = sub()
+            e = None if r is None else [op, e, r]
+        return e
+
+    def expr():
+        return chain(lambda: chain(atom, ("*", "/")), ("+", "-"))
+
+    e = expr()
+    return e if e is not None and at[0] == len(toks) else None
+
+
+def intent_of_signature(sig: dict, date_column: Optional[str]) -> Optional[dict]:
+    """The intent form of a served signature, from its public JSON fields
+    (``Signature.to_json()``): levels, measures and filters, with a time
+    window as two filters on ``date_column``.  None where the signature
+    asks what the reference does not compute (HAVING, ORDER BY, LIMIT, a
+    distinct count, a governed metric, an expression it cannot parse)."""
+    if sig.get("having") or sig.get("order_by") or sig.get("limit") is not None \
+            or sig.get("metric_id") is not None:
+        return None
+    measures = []
+    for m in sig["measures"]:
+        e = parse_expr(m["expr"])
+        if m.get("distinct") or m["agg"] not in ("SUM", "COUNT", "MIN", "MAX", "AVG") \
+                or e is None:
+            return None
+        measures.append([m["agg"], e])
+    filters = [[f["col"], f["op"], list(f["val"]) if isinstance(f["val"], (list, tuple))
+                else f["val"]] for f in sig.get("filters", ())]
+    window = sig.get("time_window")
+    if window:
+        if date_column is None:
+            return None
+        filters += [[date_column, ">=", window["start"]], [date_column, "<", window["end"]]]
+    return {"levels": list(sig.get("levels", ())), "measures": measures, "filters": filters}
 
 
 def filter_key(filters) -> tuple:

@@ -17,6 +17,12 @@ Three kinds of mix, each set out by its file alone:
   replacement, for a pool of at most ``pool_dashboards`` dashboards that
   no window may use up.
 
+Any mix may set ``nl_share`` in [0, 1]: that share of its requests, warm-up
+included, is sent as a plain-English question (``bench/lib/nl.py``) of the
+same intent in place of its SQL.  Which requests are questions comes from a
+stream of its own (``NL``), the same for every seed; a mix without
+``nl_share`` makes exactly the schedule it made before questions existed.
+
 Every seed gets the same work in the same order: the arrival times, the
 order of session ranks and of shapes come from one fixed stream (``SHAPE``),
 and the seed draws only the literals (which year and region each session
@@ -33,10 +39,12 @@ from typing import Optional
 
 import numpy as np
 
+from . import nl
 from .data import BENCH, Data, rng_for
 from .reference import render_expr
 
 SHAPE = 0  # the seed of the stream that fixes arrivals and orders
+NL = 106  # the stream of SHAPE that picks the requests sent as questions
 
 
 @dataclasses.dataclass
@@ -47,6 +55,7 @@ class Request:
     kind: str  # a name for the request's role: session step or shape
     due: float = 0.0  # seconds after the window opens (open loop)
     group: int = 0  # dashboard number (closed loop) or session number
+    nl: Optional[str] = None  # the question sent in place of ``sql``, if any
 
 
 @dataclasses.dataclass
@@ -280,19 +289,36 @@ def _req(intent, data, kind, due=0.0, group=0, rid=-1) -> Request:
     return Request(rid, intent, render_sql(intent, data), kind, due, group)
 
 
+def _questions(mix: dict, submits: list[list[Request]]) -> None:
+    """Send ``nl_share`` of the requests, in submit order, as questions."""
+    share = float(mix.get("nl_share", 0.0))
+    if not 0.0 <= share <= 1.0:
+        raise ValueError(f"nl_share {share} is not in [0, 1]")
+    if share == 0.0:
+        return
+    pick = rng_for(SHAPE, NL)
+    for reqs in submits:
+        for r in reqs:
+            if pick.random() < share:
+                r.nl = nl.question(r.intent)
+
+
 def schedule(name: str, data: Data, seed: int, seconds: float,
              mix: Optional[dict] = None) -> Schedule:
     mix = load(name) if mix is None else mix
     if mix["kind"] == "sessions":
         warm, reqs = _sessions(mix, data, seed, seconds)
-        return Schedule("open", warm, reqs, [], workers=mix.get("workers", 1))
-    if mix["kind"] == "shapes":
+        out = Schedule("open", warm, reqs, [], workers=mix.get("workers", 1))
+    elif mix["kind"] == "shapes":
         warm, reqs = _shapes(mix, data, seed, seconds)
-        return Schedule("open", warm, reqs, [], workers=mix.get("workers", 1))
-    if mix["kind"] == "dashboard":
+        out = Schedule("open", warm, reqs, [], workers=mix.get("workers", 1))
+    elif mix["kind"] == "dashboard":
         warm, dash = _dashboard(mix, data, seed)
-        return Schedule("closed", warm, [], dash, clients=mix["clients"])
-    raise ValueError(f"unknown traffic kind {mix['kind']!r}")
+        out = Schedule("closed", warm, [], dash, clients=mix["clients"])
+    else:
+        raise ValueError(f"unknown traffic kind {mix['kind']!r}")
+    _questions(mix, out.warmup + [[r] for r in out.requests] + out.dashboards)
+    return out
 
 
 def intent_key(intent: dict) -> str:

@@ -18,8 +18,11 @@ than the cell asks for, on a device missing from ``bench/peaks.json``, or
 without the program's ``src/`` beside ``bench/``.
 
 ``--control bfloat16`` puts the reference computed at bfloat16 in the
-program's place in the comparison, so that ``correct`` comes out false; the
-program's own reading is printed beside it (the readings of the limits).
+program's place in the comparison of answers, and ``--control float8`` (a
+cell with a model) the reference model with its weight matrices rounded to
+float8 in the program's place in the comparison of logits, so that
+``correct`` comes out false; the program's own reading is printed beside it
+(the readings of the limits).
 """
 import time
 
@@ -41,7 +44,7 @@ def main(argv=None) -> int:
     ap.add_argument("--seed", type=int, required=True)
     ap.add_argument("--seconds", type=float, required=True)
     ap.add_argument("--trace", type=int, choices=(0, 1), default=0)
-    ap.add_argument("--control", choices=("bfloat16",), default=None)
+    ap.add_argument("--control", choices=("bfloat16", "float8"), default=None)
     args = ap.parse_args(argv)
     try:
         out = harness.run(args.workload, args.seed, args.seconds, bool(args.trace),
